@@ -230,7 +230,10 @@ class DiffusionEngine:
                 # ops re-checks at the call site where `interpret` resolves)
                 ops.validate_page_lanes(page_size, interpret=None)
         self._jit_run_block = jax.jit(self._run_block)   # compile once, reuse
-        self._jit_step = jax.jit(self._engine_step)
+        # donated state: the KV pool and slot planes update in place instead
+        # of being held twice across the step (the scheduler reassigns
+        # ``self.state`` with the return value and keeps no other reference)
+        self._jit_step = jax.jit(self._engine_step, donate_argnums=(1,))
         # donated pool: the fork updates pages in place instead of copying
         # the whole pool (callers drop the pre-fork state immediately)
         self._jit_fork_kv = jax.jit(self._fork_kv_pools, donate_argnums=(0,))
@@ -790,7 +793,8 @@ class DiffusionEngine:
              enc_out: Optional[jax.Array] = None) -> EngineState:
         """ONE denoising iteration for every resident slot — a single jitted
         program whose shape is independent of which slots are prefilling,
-        refreshing, skip-decoding, or idle (per-row mode masks)."""
+        refreshing, skip-decoding, or idle (per-row mode masks).  ``state``
+        is donated: callers must drop it and keep the returned state."""
         return self._jit_step(params, state, enc_out)
 
     def bind_state_shardings(self, state_shardings, param_shardings=None):
@@ -804,7 +808,7 @@ class DiffusionEngine:
         self._jit_step = jax.jit(
             self._engine_step,
             in_shardings=(param_shardings, state_shardings, None),
-            out_shardings=state_shardings)
+            out_shardings=state_shardings, donate_argnums=(1,))
 
     def _merge_step_outputs(self, mask, old, new):
         """Per-row merge of one mode pass's ``(caches, conf, pred, hidden,

@@ -15,6 +15,10 @@ Mask semantics are position-based so gathered Q subsets work naturally:
 
 Block shapes are MXU/VPU aligned: head_dim padded to a multiple of 128 by the
 ops.py wrapper, block_q/block_kv multiples of 8 (f32) with 128-lane tiles.
+The position planes travel as a ``[B, Lq, 1]`` column and a ``[B, 1, Lkv]``
+row, so each block's last two dimensions are either the array's own or
+8/128-aligned (the TPU lowering refuses anything else), and the mask
+broadcast ``[bq, 1] x [1, bk]`` needs no relayout in the kernel.
 
 Paged variant
 -------------
@@ -73,8 +77,8 @@ def window_block_tables(block_tables: jax.Array, limit: jax.Array | None,
 
 
 def _flash_kernel(
-    qpos_ref,   # [1, bq] int32
-    kvpos_ref,  # [1, bk] int32
+    qpos_ref,   # [1, bq, 1] int32
+    kvpos_ref,  # [1, 1, bk] int32
     q_ref,      # [1, 1, bq, D]
     k_ref,      # [1, 1, bk, D]
     v_ref,      # [1, 1, bk, D]
@@ -107,8 +111,8 @@ def _flash_kernel(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale                                     # [bq, bk]
 
-    qp = qpos_ref[0][:, None]                     # [bq, 1]
-    kp = kvpos_ref[0][None, :]                    # [1, bk]
+    qp = qpos_ref[0]                              # [bq, 1]
+    kp = kvpos_ref[0]                             # [1, bk]
     mask = kp >= 0
     if causal:
         mask &= kp <= qp
@@ -184,8 +188,8 @@ def flash_attention_kernel(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q), lambda bi, h, qi, ki: (bi, qi)),
-            pl.BlockSpec((1, block_kv), lambda bi, h, qi, ki: (bi, ki)),
+            pl.BlockSpec((1, block_q, 1), lambda bi, h, qi, ki: (bi, qi, 0)),
+            pl.BlockSpec((1, 1, block_kv), lambda bi, h, qi, ki: (bi, 0, ki)),
             pl.BlockSpec((1, 1, block_q, d), lambda bi, h, qi, ki: (bi, h, qi, 0)),
             pl.BlockSpec(
                 (1, 1, block_kv, d), lambda bi, h, qi, ki: (bi, h // group, ki, 0)
@@ -202,7 +206,7 @@ def flash_attention_kernel(
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(q_pos, kv_pos, q, k, v)
+    )(q_pos[:, :, None], kv_pos[:, None, :], q, k, v)
 
 
 def paged_flash_attention_kernel(
@@ -254,8 +258,8 @@ def paged_flash_attention_kernel(
         num_scalar_prefetch=1,
         grid=(b, hq, lq // block_q, n_vpages),
         in_specs=[
-            pl.BlockSpec((1, block_q), lambda bi, h, qi, ki, bt: (bi, qi)),
-            pl.BlockSpec((1, ps), lambda bi, h, qi, ki, bt: (bi, ki)),
+            pl.BlockSpec((1, block_q, 1), lambda bi, h, qi, ki, bt: (bi, qi, 0)),
+            pl.BlockSpec((1, 1, ps), lambda bi, h, qi, ki, bt: (bi, 0, ki)),
             pl.BlockSpec((1, 1, block_q, d), lambda bi, h, qi, ki, bt: (bi, h, qi, 0)),
             pl.BlockSpec(
                 (1, 1, ps, d),
@@ -287,4 +291,5 @@ def paged_flash_attention_kernel(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hq, lq, d), q.dtype),
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), q_pos, kv_pos, q, k_pool, v_pool)
+    )(block_tables.astype(jnp.int32), q_pos[:, :, None], kv_pos[:, None, :],
+      q, k_pool, v_pool)

@@ -20,18 +20,35 @@ Two runtimes:
 from __future__ import annotations
 
 import argparse
+import os
+from pathlib import Path
 
 import jax
 import numpy as np
 
 from repro import configs
-from repro.configs import GenerationConfig, default_skip_stages
+from repro.configs import GenerationConfig, ModelConfig, default_skip_stages
 from repro.models import build_model
 from repro.runtime import (BatchServer, ConfigError, Request,
                            ShardedStreamScheduler, StreamScheduler)
 
+REPO_ROOT = Path(__file__).resolve().parents[3]
 
-def main() -> None:
+
+def configure_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    JAX reads ``JAX_COMPILATION_CACHE_DIR`` itself; when it is unset the
+    cache goes to ``<repo>/.jax_cache``.  The path is fixed because it is
+    part of the cache key: a directory that moves never hits."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(REPO_ROOT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llada-8b")
     ap.add_argument("--full", action="store_true",
@@ -135,8 +152,11 @@ def main() -> None:
                          "shorter width (the iteration-smoothing win); "
                          "requests with longer prompts route to the "
                          "refresh shards")
-    args = ap.parse_args()
+    return ap
 
+
+def validate(args: argparse.Namespace) -> None:
+    """Typed upfront checks of a parsed command line."""
     # fail fast on SLO/preemption misconfiguration, before any model build
     # (the scheduler re-validates --preemption, but the batch runtime never
     # reaches it, and a bad flag should not cost a params init)
@@ -210,13 +230,16 @@ def main() -> None:
         raise ConfigError(f"--placement {args.placement} needs --shards "
                           ">= 2 (a single shard has nothing to route)")
 
-    cfg = configs.get_config(args.arch)
-    if not args.full:
-        cfg = configs.reduced(cfg)
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
 
-    gen = GenerationConfig(
+def init_model(cfg: ModelConfig, seed: int = 0):
+    """Build the model and its random parameters."""
+    model = build_model(cfg)
+    return model, model.init(jax.random.PRNGKey(seed))
+
+
+def generation_config(args: argparse.Namespace,
+                      cfg: ModelConfig) -> GenerationConfig:
+    return GenerationConfig(
         gen_length=args.gen_length,
         block_length=args.block_length,
         mode=args.mode,
@@ -230,13 +253,14 @@ def main() -> None:
         block_causal=args.block_causal,
     )
 
-    stream_cb = None
-    if args.stream_print:
-        def stream_cb(req, bi, blk):
-            print(f"  [stream] req={req.request_id} block={bi}: {blk.tolist()}")
 
+def build_server(args: argparse.Namespace, model, params,
+                 gen: GenerationConfig, stream_cb=None, **engine_kw):
+    """The server the command line describes: a sharded or single
+    ``StreamScheduler``, or the lock-step ``BatchServer``.  ``engine_kw``
+    reaches the ``DiffusionEngine`` (e.g. ``attn_impl``)."""
     if args.runtime == "stream" and args.shards > 1:
-        server = ShardedStreamScheduler(
+        return ShardedStreamScheduler(
             model, params, gen, shards=args.shards,
             placement=args.placement, refresh_shards=args.refresh_shards,
             decode_prompt_len=args.decode_prompt_len,
@@ -246,20 +270,37 @@ def main() -> None:
             prefix_sharing=args.prefix_sharing,
             early_advance=args.early_advance,
             gather_refresh=args.gather_refresh,
-            lazy_reserve=args.lazy_reserve, preemption=args.preemption)
-    elif args.runtime == "stream":
-        server = StreamScheduler(model, params, gen, max_slots=args.batch,
-                                 prompt_len=args.prompt_len, stream_cb=stream_cb,
-                                 paged=args.paged, page_size=args.page_size,
-                                 kv_pages=args.kv_pages,
-                                 prefix_sharing=args.prefix_sharing,
-                                 early_advance=args.early_advance,
-                                 gather_refresh=args.gather_refresh,
-                                 lazy_reserve=args.lazy_reserve,
-                                 preemption=args.preemption)
-    else:
-        server = BatchServer(model, params, gen, batch_size=args.batch,
-                             prompt_len=args.prompt_len)
+            lazy_reserve=args.lazy_reserve, preemption=args.preemption,
+            **engine_kw)
+    if args.runtime == "stream":
+        return StreamScheduler(model, params, gen, max_slots=args.batch,
+                               prompt_len=args.prompt_len, stream_cb=stream_cb,
+                               paged=args.paged, page_size=args.page_size,
+                               kv_pages=args.kv_pages,
+                               prefix_sharing=args.prefix_sharing,
+                               early_advance=args.early_advance,
+                               gather_refresh=args.gather_refresh,
+                               lazy_reserve=args.lazy_reserve,
+                               preemption=args.preemption, **engine_kw)
+    return BatchServer(model, params, gen, batch_size=args.batch,
+                       prompt_len=args.prompt_len, **engine_kw)
+
+
+def main() -> None:
+    args = build_parser().parse_args()
+    validate(args)
+    configure_compile_cache()
+    cfg = configs.get_config(args.arch)
+    if not args.full:
+        cfg = configs.reduced(cfg)
+    model, params = init_model(cfg)
+    gen = generation_config(args, cfg)
+
+    stream_cb = None
+    if args.stream_print:
+        def stream_cb(req, bi, blk):
+            print(f"  [stream] req={req.request_id} block={bi}: {blk.tolist()}")
+    server = build_server(args, model, params, gen, stream_cb=stream_cb)
 
     rng = np.random.default_rng(0)
     if args.dup_prompts:

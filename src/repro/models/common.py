@@ -18,8 +18,12 @@ def round_up(x: int, m: int) -> int:
 
 
 def padded_vocab(cfg: ModelConfig) -> int:
-    """Vocab padded to 256 so the model axis always divides logits."""
-    return round_up(cfg.vocab_size, 256)
+    """Vocab padded to a multiple of 256 (so the model axis always divides
+    logits) with at least one pad row: the engines' mask token id is
+    ``vocab_size``, and its embedding row must exist.  (LLaDA's 126,464 is
+    already a multiple of 256; without the extra row the mask embedding
+    gathers out of bounds, which ``jnp.take`` fills with NaN.)"""
+    return round_up(cfg.vocab_size + 1, 256)
 
 
 # ---------------------------------------------------------------------------

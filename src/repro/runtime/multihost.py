@@ -256,24 +256,27 @@ class ShardedStreamScheduler:
         for s in range(shards):
             lane_params = params if devices is None \
                 else jax.device_put(params, devices[s])
-            lane = StreamScheduler(
-                model, lane_params, gen,
-                max_slots=slots_per,
-                prompt_len=lane_prompt[s],
-                pad_id=pad_id,
-                seed=seed + s,
-                stream_cb=stream_cb,
-                clock=clock,
-                paged=paged,
-                page_size=page_size,
-                kv_pages=lane_pages[s],
-                engine=shared_engine,
-                **lane_kw,
-            )
+            # build the lane's device state (tokens, pools, block tables,
+            # slot planes) on its shard's device directly, not on device 0
+            with jax.default_device(None if devices is None else devices[s]):
+                lane = StreamScheduler(
+                    model, lane_params, gen,
+                    max_slots=slots_per,
+                    prompt_len=lane_prompt[s],
+                    pad_id=pad_id,
+                    seed=seed + s,
+                    stream_cb=stream_cb,
+                    clock=clock,
+                    paged=paged,
+                    page_size=page_size,
+                    kv_pages=lane_pages[s],
+                    engine=shared_engine,
+                    **lane_kw,
+                )
             if devices is not None:
-                # pin the lane's whole device state (tokens, pools, block
-                # tables, slot planes) to its shard's device; the shared
-                # engine's jitted step follows the committed inputs
+                # commit the state to the shard's device (no copy: it is
+                # already there); the shared engine's jitted step follows
+                # the committed inputs
                 lane.state = jax.device_put(lane.state, devices[s])
             if shared_engine is None:
                 shared_engine = lane.engine

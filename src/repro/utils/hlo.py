@@ -35,15 +35,9 @@ _LINE_RE = re.compile(
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """Normalize ``Compiled.cost_analysis()`` across JAX versions.
-
-    Older JAX returns one dict per device (a list); newer JAX returns the
-    dict directly.  Either way, hand back a plain dict (empty when the
-    backend reports nothing).
-    """
+    """``Compiled.cost_analysis()`` as a plain dict (empty when the backend
+    reports nothing)."""
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     return dict(cost) if cost else {}
 
 

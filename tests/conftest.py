@@ -9,15 +9,6 @@ import jax  # noqa: E402
 
 jax.config.update("jax_enable_x64", False)
 
-# Property tests use hypothesis when installed (CI pins it); on bare
-# containers fall back to the deterministic shim so collection never breaks.
-try:
-    import hypothesis  # noqa: F401, E402
-except ModuleNotFoundError:
-    from repro.testing import hypothesis_fallback  # noqa: E402
-
-    sys.modules["hypothesis"] = hypothesis_fallback
-
 import pytest  # noqa: E402
 
 

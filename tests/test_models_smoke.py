@@ -34,6 +34,20 @@ def test_forward_shapes_and_finite(arch, rng):
 
 
 @pytest.mark.parametrize("arch", ALL_ARCHS)
+def test_mask_token_has_an_embedding_row(arch):
+    """The engines' mask id is ``vocab_size``: the padded table must hold
+    it at published widths too (an out-of-range id gathers NaN)."""
+    from repro.configs import GenerationConfig
+    from repro.core import make_engine
+    from repro.models.common import padded_vocab
+    cfg = configs.get_config(arch)
+    engine = make_engine(build_model(cfg), GenerationConfig(
+        mode="dualcache", gen_length=32, block_length=32))
+    assert cfg.vocab_size <= engine.mask_id < padded_vocab(cfg)
+    assert padded_vocab(cfg) % 256 == 0
+
+
+@pytest.mark.parametrize("arch", ALL_ARCHS)
 def test_one_train_step(arch, rng):
     cfg = configs.reduced(configs.get_config(arch))
     model = build_model(cfg)
