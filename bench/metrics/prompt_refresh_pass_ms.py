@@ -1,0 +1,8 @@
+"""Mean device ms of one run of the ``es.prompt_refresh`` pass in the traced
+tail: an execution of its conditional in which the pass's own branch ran,
+mean over the chips (``bench/scopes.py``).  None without a run."""
+
+
+def read(rec):
+    tr = rec.get("trace") or {}
+    return (tr.get("passes") or {}).get("es.prompt_refresh", {}).get("ms")

@@ -797,6 +797,16 @@ class DiffusionEngine:
         is donated: callers must drop it and keep the returned state."""
         return self._jit_step(params, state, enc_out)
 
+    def compiled_step_text(self, params, state: EngineState,
+                           enc_out: Optional[jax.Array] = None) -> str:
+        """The optimised HLO of the step compiled for these arguments' shapes
+        and shardings.  Its ``op_name`` metadata maps the instruction names
+        a profiler trace shows (``cond.57``) to the named scopes of the
+        passes and of attention.  Lowering reuses the cached trace of the
+        step (``step_trace_count`` stays as it is); compiling takes as long
+        as the step's own compile unless the persistent cache holds it."""
+        return self._jit_step.lower(params, state, enc_out).compile().as_text()
+
     def bind_state_shardings(self, state_shardings, param_shardings=None):
         """Rebind the jitted step with explicit ``EngineState`` shardings
         (multi-host step 2: ``sharding.specs.engine_state_pspecs`` →
@@ -913,20 +923,28 @@ class DiffusionEngine:
         skip_rows = state.active & (br == 0)
         noskip_rows = state.active & (br == 1)
         refresh_rows = state.active & (br == 2)
-        carry = jax.lax.cond(jnp.any(skip_rows),
-                             decode_pass(True, skip_rows), lambda c: c, carry)
-        carry = jax.lax.cond(jnp.any(noskip_rows),
-                             decode_pass(False, noskip_rows), lambda c: c,
-                             carry)
-        carry = jax.lax.cond(jnp.any(refresh_rows),
-                             prefill_pass(refresh_rows), lambda c: c, carry)
+        # each pass's conditional carries a named scope (HLO op_name
+        # metadata only): profiler traces name the passes by it
+        with jax.named_scope("es.skip_decode"):
+            carry = jax.lax.cond(jnp.any(skip_rows),
+                                 decode_pass(True, skip_rows), lambda c: c,
+                                 carry)
+        with jax.named_scope("es.block_refresh"):
+            carry = jax.lax.cond(jnp.any(noskip_rows),
+                                 decode_pass(False, noskip_rows), lambda c: c,
+                                 carry)
+        with jax.named_scope("es.prompt_refresh"):
+            carry = jax.lax.cond(jnp.any(refresh_rows),
+                                 prefill_pass(refresh_rows), lambda c: c,
+                                 carry)
         if self.adaptive_cache:
             # branch 3 is only ever emitted with the cache enabled; gating
             # statically keeps the disabled program byte-identical
             partial_rows = state.active & (br == 3)
-            carry = jax.lax.cond(jnp.any(partial_rows),
-                                 partial_pass(partial_rows), lambda c: c,
-                                 carry)
+            with jax.named_scope("es.partial_refresh"):
+                carry = jax.lax.cond(jnp.any(partial_rows),
+                                     partial_pass(partial_rows), lambda c: c,
+                                     carry)
         return carry
 
     def _engine_step(self, params, state: EngineState, enc_out) -> EngineState:

@@ -280,6 +280,7 @@ class ShardedStreamScheduler:
                 lane.state = jax.device_put(lane.state, devices[s])
             if shared_engine is None:
                 shared_engine = lane.engine
+            lane.lane_index = s
             self.lanes.append(lane)
         self.engine = shared_engine
         self.allocator = ShardedPageAllocator(

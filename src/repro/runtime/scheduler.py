@@ -95,6 +95,9 @@ from repro.runtime.errors import (
 )
 from repro.runtime.request import Request, StreamCallback
 
+# a host span on the profiler's trace; costs one object when none runs
+_span = jax.profiler.TraceAnnotation
+
 
 @dataclasses.dataclass
 class SchedulerStats:
@@ -599,6 +602,9 @@ class StreamScheduler:
         # "reserve": {slot: [pages]}, "born": step} — see _admit/_cow_fork
         self.cohorts: list[dict] = []
         self._step_count = 0
+        # set by ShardedStreamScheduler: the lane's index, an argument of
+        # its ``es.sched.step`` profiler span
+        self.lane_index: Optional[int] = None
         self.stats = SchedulerStats()
         if self.allocator is not None:
             self.stats.pages_total = self.allocator.num_pages - 1
@@ -1168,96 +1174,119 @@ class StreamScheduler:
         completion bookkeeping all key on the per-slot phase vector.  With
         ``early_advance=False`` the phases stay mutually aligned (admission
         and advancement only happen when every slot wraps together), so the
-        behavior reduces exactly to the old block-aligned scheduler."""
+        behavior reduces exactly to the old block-aligned scheduler.
+
+        Each phase runs inside a profiler span (``es.sched.step`` around
+        the call; ``es.sched.admit``, ``es.sched.prepare``,
+        ``es.engine.dispatch``, ``es.engine.wait``, ``es.sched.after``,
+        ``es.sched.retire``, ``es.sched.grow`` inside it), which records
+        nothing unless a ``jax.profiler`` trace is running."""
+        args = {} if self.lane_index is None else {"lane": self.lane_index}
+        with _span("es.sched.step", **args):
+            return self._step()
+
+    def _step(self) -> bool:
         t0 = self.clock()           # admission work (incl. encode) is wall time
-        phases = np.asarray(self.state.phase)
-        if (self.queue or self._spilled) and bool(phases.any()) \
-                and not any(r is not None for r in self.slot_req):
-            # quarantine (unlike normal retirement) can retire the LAST
-            # resident mid-block, freezing every phase counter off the
-            # boundary — with nobody resident the counters are meaningless,
-            # but the aligned admission gate reads them, so re-zero or the
-            # gate never reopens and queued work starves a free pool
-            self.state = self.state._replace(
-                phase=jnp.zeros_like(self.state.phase))
+        with _span("es.sched.admit"):
             phases = np.asarray(self.state.phase)
-        if self.early_advance or bool((phases == 0).all()):
-            self._admit()
-            phases = np.asarray(self.state.phase)
+            if (self.queue or self._spilled) and bool(phases.any()) \
+                    and not any(r is not None for r in self.slot_req):
+                # quarantine (unlike normal retirement) can retire the LAST
+                # resident mid-block, freezing every phase counter off the
+                # boundary — with nobody resident the counters are
+                # meaningless, but the aligned admission gate reads them, so
+                # re-zero or the gate never reopens and queued work starves
+                # a free pool
+                self.state = self.state._replace(
+                    phase=jnp.zeros_like(self.state.phase))
+                phases = np.asarray(self.state.phase)
+            if self.early_advance or bool((phases == 0).all()):
+                self._admit()
+                phases = np.asarray(self.state.phase)
         resident = np.asarray([r is not None for r in self.slot_req])
         if not resident.any():
             return False
-        # rows whose upcoming step is a prompt refresh — the only branch
-        # that scatters into THAT row's prompt pages — per the engine's own
-        # per-row cadence
-        refresh_rows = self.engine.prompt_refresh_rows(phases) & resident
-        if self.stalled:
-            # a stalled row is frozen (inactive on device, phase drifting):
-            # its phase vector entry no longer describes an upcoming refresh,
-            # so keep it out of the CoW-fork / reclaim hooks until resume
-            stalled_mask = np.zeros(self.max_slots, bool)
-            stalled_mask[list(self.stalled)] = True
-            refresh_rows &= ~stalled_mask
-        if self.paged and refresh_rows.any():
-            self._cow_fork_before_refresh(refresh_rows)
-        if self.gen.block_causal and refresh_rows.any():
-            # gauge: positions the upcoming FULL refreshes will leave in
-            # place (same elementwise horizon the engine's refresh token
-            # mask uses, so the two can never drift apart)
-            bs_h = np.asarray(self.state.bs)
-            it_h = np.asarray(self.state.iters)
-            full_r = np.asarray(full_refresh_pred(self.gen, it_h), bool)
-            inv = np.asarray(invariant_limit(
-                self.gen, bs_h, it_h, self.prompt_len))
-            skipped = np.maximum(
-                inv - np.asarray(self.state.prompt_start), 0)
-            self.stats.invariant_tokens_skipped += int(
-                skipped[refresh_rows & full_r].sum())
-        pre_blocks_left = np.asarray(self.state.blocks_left)
-        track_cache = self.state.feat is not None
-        if track_cache:
-            # cumulative per-slot counters (reset on admission): the step
-            # delta is this iteration's refresh activity
-            pre_r = np.asarray(self.state.cache_refreshed)
-            pre_e = np.asarray(self.state.cache_eligible)
-        self.state = self.engine.step(self.params, self.state, self._enc_out)
-        jax.block_until_ready(self.state.tokens)
+        with _span("es.sched.prepare"):
+            # rows whose upcoming step is a prompt refresh — the only branch
+            # that scatters into THAT row's prompt pages — per the engine's
+            # own per-row cadence
+            refresh_rows = self.engine.prompt_refresh_rows(phases) & resident
+            if self.stalled:
+                # a stalled row is frozen (inactive on device, phase
+                # drifting): its phase vector entry no longer describes an
+                # upcoming refresh, so keep it out of the CoW-fork / reclaim
+                # hooks until resume
+                stalled_mask = np.zeros(self.max_slots, bool)
+                stalled_mask[list(self.stalled)] = True
+                refresh_rows &= ~stalled_mask
+            if self.paged and refresh_rows.any():
+                self._cow_fork_before_refresh(refresh_rows)
+            if self.gen.block_causal and refresh_rows.any():
+                # gauge: positions the upcoming FULL refreshes will leave in
+                # place (same elementwise horizon the engine's refresh token
+                # mask uses, so the two can never drift apart)
+                bs_h = np.asarray(self.state.bs)
+                it_h = np.asarray(self.state.iters)
+                full_r = np.asarray(full_refresh_pred(self.gen, it_h), bool)
+                inv = np.asarray(invariant_limit(
+                    self.gen, bs_h, it_h, self.prompt_len))
+                skipped = np.maximum(
+                    inv - np.asarray(self.state.prompt_start), 0)
+                self.stats.invariant_tokens_skipped += int(
+                    skipped[refresh_rows & full_r].sum())
+            pre_blocks_left = np.asarray(self.state.blocks_left)
+            track_cache = self.state.feat is not None
+            if track_cache:
+                # cumulative per-slot counters (reset on admission): the step
+                # delta is this iteration's refresh activity
+                pre_r = np.asarray(self.state.cache_refreshed)
+                pre_e = np.asarray(self.state.cache_eligible)
+        with _span("es.engine.dispatch"):
+            self.state = self.engine.step(self.params, self.state,
+                                          self._enc_out)
+        with _span("es.engine.wait"):
+            jax.block_until_ready(self.state.tokens)
         self._step_count += 1
         dt = self.clock() - t0
         self.stats.wall_s += dt
         # per-step wall EWMA: the measured-cost term of deadline admission
         self._step_ewma = dt if self._step_ewma is None \
             else 0.8 * self._step_ewma + 0.2 * dt
-        if track_cache:
-            d_r = np.asarray(self.state.cache_refreshed) - pre_r
-            d_e = np.asarray(self.state.cache_eligible) - pre_e
-            self.stats.cache_refreshed_total += int(d_r.sum())
-            self.stats.cache_eligible_total += int(d_e.sum())
-            self.stats.refresh_event_tokens.extend(d_r[d_e > 0].tolist())
-        if self.state.poisoned is not None:
-            # quarantine BEFORE reclaim/retirement bookkeeping: a poisoned
-            # row must never reach the streaming or page-eviction paths
-            pois = np.asarray(self.state.poisoned)
-            if pois.any():
-                self._quarantine([int(s) for s in np.nonzero(pois)[0]])
-        if self.paged and self.gen.sparse_attention and refresh_rows.any():
-            self._reclaim_dead_pages(refresh_rows)
-        if self.early_advance:
-            adv = (np.asarray(self.state.blocks_left) < pre_blocks_left) \
-                & resident
-            steps_pb = self.gen.resolved_steps()
-            self.stats.early_advances += int(
-                (adv & ((phases + 1) % steps_pb != 0)).sum())
-            # streams / retires per iteration: a finished row's slot is free
-            # for the very next admission, not for the end of a cycle
-            self._finish_cycle()
-        elif bool((np.asarray(self.state.phase) == 0).all()):
-            self._finish_cycle()
+        with _span("es.sched.after"):
+            if track_cache:
+                d_r = np.asarray(self.state.cache_refreshed) - pre_r
+                d_e = np.asarray(self.state.cache_eligible) - pre_e
+                self.stats.cache_refreshed_total += int(d_r.sum())
+                self.stats.cache_eligible_total += int(d_e.sum())
+                self.stats.refresh_event_tokens.extend(d_r[d_e > 0].tolist())
+            if self.state.poisoned is not None:
+                # quarantine BEFORE reclaim/retirement bookkeeping: a
+                # poisoned row must never reach the streaming or
+                # page-eviction paths
+                pois = np.asarray(self.state.poisoned)
+                if pois.any():
+                    self._quarantine([int(s) for s in np.nonzero(pois)[0]])
+            if self.paged and self.gen.sparse_attention and refresh_rows.any():
+                self._reclaim_dead_pages(refresh_rows)
+        with _span("es.sched.retire"):
+            if self.early_advance:
+                adv = (np.asarray(self.state.blocks_left) < pre_blocks_left) \
+                    & resident
+                steps_pb = self.gen.resolved_steps()
+                self.stats.early_advances += int(
+                    (adv & ((phases + 1) % steps_pb != 0)).sum())
+                # streams / retires per iteration: a finished row's slot is
+                # free for the very next admission, not for the end of a
+                # cycle
+                self._finish_cycle()
+            elif bool((np.asarray(self.state.phase) == 0).all()):
+                self._finish_cycle()
         if self.lazy_reserve:
             # AFTER retirement so pages freed this step are grantable this
             # step; runs every iteration because aligned mode advances bs at
             # the phase wrap, not through the early_advance bookkeeping
-            self._grow_windows()
+            with _span("es.sched.grow"):
+                self._grow_windows()
         return True
 
     # ------------------------------------------------------------------
