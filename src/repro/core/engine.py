@@ -199,9 +199,6 @@ class DiffusionEngine:
         early_advance: bool = False,         # serving: advance a row's block
                                              # the moment it fully unmasks
                                              # (else: shared-boundary advance)
-        gather_refresh: bool = False,        # serving: compact refreshing rows
-                                             # to a half-width prefill pass
-                                             # (paged, attention-only archs)
     ):
         self.model = model
         self.cfg = model.cfg
@@ -276,13 +273,13 @@ class DiffusionEngine:
                 "adaptive feature cache needs >=1 skip stage as its probe "
                 "boundary; use a zero-ratio stage (SkipStage(l, 0.0))")
             self.cache_probe_groups = self.segments[0].group_hi
-        self.gather_refresh = gather_refresh
-        if gather_refresh:
-            assert paged, "gather_refresh compaction needs the paged KV pool " \
-                "(batch-free pool planes make row gathering transparent)"
-            assert all(k == "attn" for k, _ in model.layer_info), (
-                "gather_refresh: attention-only archs (cross/SSM caches are "
-                "batch-major and would need a second gather/scatter path)")
+        # the serving step's prompt refresh runs one row at a time over the
+        # refreshing rows only where every cache is the batch-free paged
+        # pool: a row's block-table row then routes its K/V writes in place.
+        # Dense KV and cross/SSM caches are batch-major, and keep the masked
+        # pass over every slot.
+        self.refresh_per_row = paged and all(
+            k == "attn" for k, _ in model.layer_info)
 
     # ------------------------------------------------------------------
     # per-row block indexing
@@ -342,8 +339,8 @@ class DiffusionEngine:
         disabled (``window_blocks == 0``) so the clamp is compiled out and
         the program is structurally identical to the unwindowed engine.
         Every step derives the horizon from the row's own ``bs``, so the
-        offline block loop, the mixed-mode serving step, and the compacted
-        gather-refresh pass (which gathers ``bs``) share one truth."""
+        offline block loop, the mixed-mode serving step, and its one-row
+        prompt refresh (which slices ``bs``) share one truth."""
         return resolve_window_limit(self.gen, bs)
 
     def _bc_args(self, t_total: int) -> dict:
@@ -862,7 +859,9 @@ class DiffusionEngine:
         """Mixed-mode compute for ONE serving iteration: every row resolves
         its branch from its OWN phase, and up to three fused sub-programs run
         — each gated by ``lax.cond`` on "any active row in this mode", each
-        masked to the rows it owns.  The carried ``(caches, conf, pred,
+        masked to the rows it owns (on a paged attention-only engine the
+        prompt refresh instead runs one row at a time over its own rows
+        alone, ``_refresh_rows``).  The carried ``(caches, conf, pred,
         hidden, kv_valid)`` threads through the passes; their row sets are
         disjoint, so order cannot matter semantically (passes read only
         their own rows' cache state — attention never crosses rows, and
@@ -887,27 +886,16 @@ class DiffusionEngine:
             return run
 
         def prefill_pass(mask):
+            if self.refresh_per_row:
+                return functools.partial(self._refresh_rows, params, state,
+                                         st, mask)
+
             def run(carry):
                 out = self._prefill_step(params, bs, iters, seeds,
                                          prompt_start, bt, enc_out,
                                          carried(carry), row_mask=mask)
                 return self._merge_step_outputs(mask, carry, out)
-
-            def run_compact(carry):
-                return self._compact_prefill(params, bs, iters, seeds,
-                                             prompt_start, bt, enc_out,
-                                             carried(carry), carry, mask)
-            if not self.gather_refresh:
-                return run
-            cap = max(1, b // 2)
-
-            def dispatch(carry):
-                # gathered-subset refresh: when at most half the slots are
-                # refreshing, compact them into a half-width prefill so one
-                # refreshing row no longer pays for all B rows
-                return jax.lax.cond(jnp.sum(mask) <= cap,
-                                    run_compact, run, carry)
-            return dispatch
+            return run
 
         def partial_pass(mask):
             def run(carry):
@@ -1316,55 +1304,57 @@ class DiffusionEngine:
                           axis=1).astype(jnp.int32)
         return out7[:5] + (feat, stats)
 
-    def _compact_prefill(self, params, bs, iters, seeds, prompt_start,
-                         block_tables, enc_out, st: BlockState, carry, mask):
-        """Gathered-subset prompt refresh (``gather_refresh=True``).
+    def _refresh_rows(self, params, state: EngineState, st: BlockState,
+                      mask, carry):
+        """Prompt refresh of the rows in ``mask`` alone, one row at a time.
 
-        When at most half the batch is refreshing this step, gather the
-        refreshing rows (plus filler) to the front, run ``_prefill_step``
-        on the compacted half-batch, and scatter the outputs back.  Paged
-        pools are batch-free ([G, P, ps, H, D] leaves addressed through
-        ``block_tables``), so gathering the *block tables* redirects the
-        compacted rows to their own pages and the cache writes land in
-        place — no pool gather/scatter needed (why this path asserts paged
-        + attention-only).  Cuts full-sequence refresh FLOPs ~2x on mixed
-        steps where a single long-prompt row triggers the refresh."""
-        b = mask.shape[0]
-        cap = max(1, b // 2)
-        # stable argsort: refreshing rows first, original order preserved
-        rows = jnp.argsort(~mask)[:cap]
-        sub_mask = jnp.take(mask, rows)
+        A loop over the ``sum(mask)`` refreshing rows, taken in slot order
+        (stable argsort), runs ``_prefill_step`` on a one-row batch: the
+        row's planes are sliced out of the carry and its outputs put back.
+        The paged pools are batch-free ([G, P, ps, H, D] leaves addressed
+        through ``block_tables``), so the row's own block-table row routes
+        its K/V scatters to its pages and the pool threads through the loop
+        with nothing gathered.  A step with one refreshing row pays one
+        full-sequence row, not one for every slot.
 
-        def g(a):
-            return None if a is None else jnp.take(a, rows, axis=0)
+        The serial passes compute what one masked pass over the batch does:
+        attention never crosses rows, and the only pages two rows share are
+        a greedy prefix cohort's (identical bytes on both rows) or prompt
+        pages exempt from rewrite under block-causal attention.  The
+        engine's stack is attention-only, so no encoder output is read."""
+        order = jnp.argsort(~mask)       # stable: refreshing rows first
+        owned = jnp.ones((1,), bool)     # a one-row pass owns its row
 
-        st_g = st._replace(
-            tokens=g(st.tokens), conf=g(st.conf), pred=g(st.pred),
-            hidden=tuple(g(hh) for hh in st.hidden),
-            kv_valid=g(st.kv_valid), feat=g(st.feat),
-            conf_full=g(st.conf_full),
-        )
-        out = self._prefill_step(params, g(bs), g(iters), g(seeds),
-                                 g(prompt_start), g(block_tables), enc_out,
-                                 st_g, row_mask=sub_mask)
-        caches, conf, pred, hidden, kv_valid, feat, stats = out
+        def body(i, carry):
+            r = order[i]
 
-        def put(full, sub):
-            if full is None:
-                return None
-            m = sub_mask.reshape((cap,) + (1,) * (sub.ndim - 1))
-            keep = jnp.where(m, sub.astype(full.dtype),
-                             jnp.take(full, rows, axis=0))
-            return full.at[rows].set(keep)
+            def row(a):
+                return None if a is None else \
+                    jax.lax.dynamic_slice_in_dim(a, r, 1)
 
-        o_caches, o_conf, o_pred, o_hidden, o_kv, o_feat, o_stats = carry
-        return (
-            caches,  # batch-free paged pools: writes already landed in place
-            put(o_conf, conf), put(o_pred, pred),
-            tuple(put(o, s) for o, s in zip(o_hidden, hidden)),
-            put(o_kv, kv_valid), put(o_feat, feat),
-            put(o_stats, stats),
-        )
+            def put(full, new):
+                return None if full is None else \
+                    jax.lax.dynamic_update_slice_in_dim(
+                        full, new.astype(full.dtype), r, 0)
+
+            caches, conf, pred, hidden, kv_valid, feat, stats = carry
+            st_r = st._replace(
+                tokens=row(st.tokens), caches=caches, conf=row(conf),
+                pred=row(pred), hidden=tuple(row(h) for h in hidden),
+                kv_valid=row(kv_valid), feat=row(feat),
+                conf_full=row(st.conf_full))
+            out = self._prefill_step(
+                params, row(state.bs), row(state.iters),
+                row(state.sample_seeds), row(state.prompt_start),
+                row(state.block_tables), None, st_r, row_mask=owned)
+            n_caches, n_conf, n_pred, n_hidden, n_kv, n_feat, n_stats = out
+            return (n_caches, put(conf, n_conf), put(pred, n_pred),
+                    tuple(put(o, n) for o, n in zip(hidden, n_hidden)),
+                    put(kv_valid, n_kv), put(feat, n_feat),
+                    put(stats, n_stats))
+
+        return jax.lax.fori_loop(0, jnp.sum(mask, dtype=jnp.int32), body,
+                                 carry)
 
     def _vanilla_compute(self, params, st: BlockState, bs, enc_out,
                          iters=None, seeds=None):

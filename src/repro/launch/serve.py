@@ -97,10 +97,6 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--cache-variation-threshold", type=float, default=0.0,
                     help="minimum variation score a candidate token needs "
                          "for its K/V to be recomputed in a partial refresh")
-    ap.add_argument("--gather-refresh", action="store_true",
-                    help="compact refreshing rows into a half-width prefill "
-                         "when at most half the slots refresh together "
-                         "(requires --paged)")
     ap.add_argument("--window-blocks", type=int, default=0,
                     help="sliding active window: attention reads at most "
                          "this many generation blocks past the current one "
@@ -269,7 +265,6 @@ def build_server(args: argparse.Namespace, model, params,
             page_size=args.page_size, kv_pages=args.kv_pages,
             prefix_sharing=args.prefix_sharing,
             early_advance=args.early_advance,
-            gather_refresh=args.gather_refresh,
             lazy_reserve=args.lazy_reserve, preemption=args.preemption,
             **engine_kw)
     if args.runtime == "stream":
@@ -279,7 +274,6 @@ def build_server(args: argparse.Namespace, model, params,
                                kv_pages=args.kv_pages,
                                prefix_sharing=args.prefix_sharing,
                                early_advance=args.early_advance,
-                               gather_refresh=args.gather_refresh,
                                lazy_reserve=args.lazy_reserve,
                                preemption=args.preemption, **engine_kw)
     return BatchServer(model, params, gen, batch_size=args.batch,

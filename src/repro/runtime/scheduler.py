@@ -139,6 +139,11 @@ class SchedulerStats:
     cache_eligible_total: int = 0        # past-token K/V rows a refresh saw
     refresh_event_tokens: list = dataclasses.field(default_factory=list)
                                          # tokens refreshed per refresh event
+    # the engine's prompt-refresh pass (FULL refreshes: one full-sequence
+    # row each on a paged attention-only engine, so the pass's device time
+    # grows with the rows it refreshes)
+    refresh_passes: int = 0              # steps with >=1 row at full refresh
+    refresh_rows: int = 0                # rows those steps refreshed
     # persistent cross-request prefix cache (block-causal mode only; all 0
     # otherwise).  A *hit* admits a request whose full prompt pages were
     # already resident — zero prompt-page allocations; an *eviction* drops
@@ -185,6 +190,13 @@ class SchedulerStats:
         return float(np.percentile(np.asarray(self.refresh_event_tokens), 50))
 
     @property
+    def refresh_rows_per_pass(self) -> float:
+        """Mean rows a prompt-refresh pass refreshed (0.0 before any)."""
+        if not self.refresh_passes:
+            return 0.0
+        return self.refresh_rows / self.refresh_passes
+
+    @property
     def resume_p50(self) -> float:
         """Median seconds a preempted request spent parked on the host."""
         if not self.resume_waits:
@@ -208,6 +220,7 @@ class SchedulerStats:
             "admission_wait_p50": self.admission_wait_p50,
             "cache_hit_fraction": self.cache_hit_fraction,
             "tokens_refreshed_p50": self.tokens_refreshed_p50,
+            "refresh_rows_per_pass": self.refresh_rows_per_pass,
             "prefix_hits": self.prefix_hits,
             "prefix_evictions": self.prefix_evictions,
             "invariant_tokens_skipped": self.invariant_tokens_skipped,
@@ -1221,19 +1234,26 @@ class StreamScheduler:
                 refresh_rows &= ~stalled_mask
             if self.paged and refresh_rows.any():
                 self._cow_fork_before_refresh(refresh_rows)
-            if self.gen.block_causal and refresh_rows.any():
-                # gauge: positions the upcoming FULL refreshes will leave in
-                # place (same elementwise horizon the engine's refresh token
-                # mask uses, so the two can never drift apart)
-                bs_h = np.asarray(self.state.bs)
+            if refresh_rows.any():
+                # the rows the prompt-refresh pass runs: FULL refreshes (the
+                # adaptive cache's partial ones run a pass of their own)
                 it_h = np.asarray(self.state.iters)
-                full_r = np.asarray(full_refresh_pred(self.gen, it_h), bool)
-                inv = np.asarray(invariant_limit(
-                    self.gen, bs_h, it_h, self.prompt_len))
-                skipped = np.maximum(
-                    inv - np.asarray(self.state.prompt_start), 0)
-                self.stats.invariant_tokens_skipped += int(
-                    skipped[refresh_rows & full_r].sum())
+                full_r = refresh_rows & np.asarray(
+                    full_refresh_pred(self.gen, it_h), bool)
+                if full_r.any():
+                    self.stats.refresh_passes += 1
+                    self.stats.refresh_rows += int(full_r.sum())
+                if self.gen.block_causal:
+                    # gauge: positions the upcoming FULL refreshes will leave
+                    # in place (same elementwise horizon the engine's refresh
+                    # token mask uses, so the two can never drift apart)
+                    inv = np.asarray(invariant_limit(
+                        self.gen, np.asarray(self.state.bs), it_h,
+                        self.prompt_len))
+                    skipped = np.maximum(
+                        inv - np.asarray(self.state.prompt_start), 0)
+                    self.stats.invariant_tokens_skipped += int(
+                        skipped[full_r].sum())
             pre_blocks_left = np.asarray(self.state.blocks_left)
             track_cache = self.state.feat is not None
             if track_cache:

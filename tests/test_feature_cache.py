@@ -12,7 +12,9 @@ contract"):
     bit-identical to the uncached engine;
   * cached generation is dense-vs-paged bit-identical and
     serving-vs-offline replay bit-identical, including mid-cycle
-    (early-advance) admission and the gathered-subset refresh path;
+    (early-advance) admission and the prompt refresh that runs one row at
+    a time over the refreshing rows (0, 1, 2 or every slot in one step,
+    and a block-causal prefix-sharing cohort refreshing together);
   * the variation kernel matches its XLA reference bit-for-bit in
     interpret mode;
   * the cadence: the k-th scheduled refresh is FULL iff
@@ -28,7 +30,7 @@ import pytest
 
 from repro import configs
 from repro.configs import GenerationConfig, SkipStage
-from repro.core.engine import DiffusionEngine
+from repro.core.engine import BlockState, DiffusionEngine
 from repro.core.schedule import branch_index, full_refresh_pred
 from repro.kernels import ops
 from repro.models import build_model
@@ -128,7 +130,7 @@ def test_variation_score_xla_matches_pallas_interpret():
 
 
 # ---------------------------------------------------------------------------
-# serving: mid-cycle admission + gathered-subset refresh
+# serving: mid-cycle admission + the one-row prompt refresh
 # ---------------------------------------------------------------------------
 
 
@@ -169,22 +171,191 @@ def test_cached_serving_equals_offline_replay(small_model):
     assert sched.stats.tokens_refreshed_p50 > 0
 
 
-def test_gather_refresh_bit_identical(small_model):
-    """The gathered-subset (compact) prompt refresh is a pure execution-plan
-    change: outputs must match the ungathered scheduler bit for bit, cache
-    on or off."""
+SLOTS = 4
+ROW_CACHES = {"off": {}, "adaptive": dict(cache_prompt_interval=2),
+              "sparse": dict(sparse_attention=True, sparse_retention=0.5)}
+
+
+@pytest.fixture(scope="module")
+def row_engines(small_model):
+    """Per cache variant: its sampled config, a SLOTS-slot serving engine
+    and an offline engine, built once and shared by the tests below so
+    each program compiles once."""
     cfg, model, params = small_model
-    rng = np.random.default_rng(5)
-    prompts = [rng.integers(3, cfg.vocab_size,
-                            int(rng.integers(4, PROMPT_LEN + 1)))
-               .astype(np.int32) for _ in range(5)]
-    for g in (_cfg(), _cfg(cache_prompt_interval=2)):
-        mk = lambda: [Request(prompt=p.copy(), sample_seed=i)
-                      for i, p in enumerate(prompts)]
-        plain, _ = _serve(model, params, g, mk())
-        compact, _ = _serve(model, params, g, mk(), gather_refresh=True)
-        for a, b in zip(plain, compact):
-            np.testing.assert_array_equal(a, b)
+    built = {}
+
+    def get(cache):
+        if cache not in built:
+            g = _cfg(temperature=0.7, **ROW_CACHES[cache])
+            n_vp = (PROMPT_LEN + GEN["gen_length"]) // PS
+            built[cache] = (
+                g,
+                DiffusionEngine(model, g, paged=True, page_size=PS,
+                                kv_pages=SLOTS * n_vp + 1,
+                                early_advance=True),
+                DiffusionEngine(model, g, paged=True, page_size=PS))
+        return built[cache]
+    return get
+
+
+def _serve_staggered(model, params, gcfg, reqs, together, **skw):
+    """Serve ``reqs`` on SLOTS paged slots: the first ``together`` in one
+    step, so their rows refresh together at every block start, then one
+    more after each step, so each of those refreshes a step apart."""
+    sched = StreamScheduler(model, params, gcfg, max_slots=SLOTS,
+                            prompt_len=PROMPT_LEN, paged=True, page_size=PS,
+                            early_advance=True, seed=0, **skw)
+    it = iter(reqs)
+    for r in [next(it) for _ in range(max(together, 1))]:
+        sched.submit(r)
+    while sched.has_work():
+        sched.step()
+        nxt = next(it, None)
+        if nxt is not None:
+            sched.submit(nxt)
+    done = {r.request_id: r.output for r in sched.drain()}
+    return [done[r.request_id] for r in reqs], sched
+
+
+def _offline(eng, params, reqs):
+    return np.asarray(eng.generate(
+        params, jnp.asarray(pad_and_stack(reqs, 0, PROMPT_LEN)),
+        jax.random.PRNGKey(0),
+        prompt_start=jnp.asarray([PROMPT_LEN - len(r.prompt) for r in reqs]),
+        sample_seeds=jnp.asarray([r.sample_seed for r in reqs])))
+
+
+def _varied_requests(cfg, n, seed):
+    rng = np.random.default_rng(seed)
+    return [Request(prompt=rng.integers(3, cfg.vocab_size,
+                                        int(rng.integers(4, PROMPT_LEN + 1)))
+                    .astype(np.int32), sample_seed=100 + i)
+            for i in range(n)]
+
+
+@pytest.mark.parametrize("cache", list(ROW_CACHES))
+@pytest.mark.parametrize("together", [0, 1, 2, SLOTS])
+def test_row_refresh_equals_offline_replay(small_model, row_engines,
+                                           together, cache):
+    """The prompt refresh of a paged attention-only engine runs one row at
+    a time over the rows that refresh: with 0, 1, 2 or all SLOTS rows
+    refreshing in one step, sampled, with variable-length prompts, with
+    the adaptive cache off and on, and under sparse eviction (whose
+    ``kv_valid`` the loop writes back), every request replays its offline
+    ``generate()`` bit for bit.  With none refreshing the loop is the
+    identity."""
+    cfg, model, params = small_model
+    g, eng, offline = row_engines(cache)
+    reqs = _varied_requests(cfg, SLOTS, 5 + together)
+    outs, sched = _serve_staggered(model, params, g, reqs, together,
+                                   engine=eng)
+    assert eng.refresh_per_row and eng.step_trace_count == 1
+    ref = _offline(offline, params, reqs)
+    for i in range(SLOTS):
+        np.testing.assert_array_equal(outs[i], ref[i, PROMPT_LEN:],
+                                      err_msg=f"request {i}")
+    if together == SLOTS:
+        # rows admitted in one step stay in step: every pass takes them all
+        assert sched.stats.refresh_rows_per_pass == SLOTS
+    if together == 0:
+        state = sched.state
+        st = BlockState(state.tokens, state.caches, state.conf, state.pred,
+                        state.hidden, state.kv_valid, state.phase, state.key,
+                        state.feat, state.conf_full)
+        carry = (st.caches, st.conf, st.pred, st.hidden, st.kv_valid,
+                 st.feat, jnp.ones((SLOTS, 2), jnp.int32))
+        none = jnp.zeros((SLOTS,), bool)
+        out = jax.jit(lambda c: eng._refresh_rows(params, state, st, none,
+                                                  c))(carry)
+        for a, b in zip(jax.tree_util.tree_leaves(carry),
+                        jax.tree_util.tree_leaves(out)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_row_refresh_gqa_bias_equals_offline_replay():
+    """The one-row refresh on a grouped-query stack with q/k/v bias
+    (reduced Dream-7B): two rows refreshing together and one a step
+    behind replay their offline ``generate()`` bit for bit."""
+    cfg = dataclasses.replace(configs.reduced(configs.get_config("dream-7b")),
+                              n_layers=4)
+    assert cfg.n_kv_heads < cfg.n_heads and cfg.qkv_bias
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    g = _cfg(temperature=0.7)
+    reqs = _varied_requests(cfg, 3, 21)
+    outs, sched = _serve_staggered(model, params, g, reqs, 2)
+    assert sched.engine.refresh_per_row
+    ref = _offline(DiffusionEngine(model, g, paged=True, page_size=PS),
+                   params, reqs)
+    for i in range(len(reqs)):
+        np.testing.assert_array_equal(outs[i], ref[i, PROMPT_LEN:],
+                                      err_msg=f"request {i}")
+
+
+def test_row_refresh_prefix_cohort_block_causal(small_model):
+    """A block-causal prefix-sharing cohort (one prompt, shared prompt
+    pages) whose rows refresh together in the one-row loop decodes exactly
+    as the same requests served without sharing, in the same mode: the
+    shared pages are exempt from rewrite, so no row of the loop sees
+    another's writes."""
+    cfg, model, params = small_model
+    g = _cfg(temperature=0.7, block_causal=True)
+    rng = np.random.default_rng(11)
+    prompt = rng.integers(3, cfg.vocab_size, PROMPT_LEN).astype(np.int32)
+
+    def mk():
+        return [Request(prompt=prompt.copy(), sample_seed=100 + i)
+                for i in range(3)]
+    reqs = mk()
+    sched = StreamScheduler(model, params, g, max_slots=SLOTS,
+                            prompt_len=PROMPT_LEN, paged=True, page_size=PS,
+                            early_advance=True, seed=0, prefix_sharing=True)
+    for r in reqs:
+        sched.submit(r)
+    sched.step()
+    assert sched.stats.shared_mappings > 0, "the cohort shares its prompt"
+    sched.drain()
+    shared = [r.output for r in reqs]
+    assert sched.stats.refresh_rows_per_pass == 3.0
+    plain, _ = _serve_staggered(model, params, g, mk(), 3,
+                                engine=sched.engine)
+    for a, b in zip(shared, plain):
+        np.testing.assert_array_equal(a, b)
+    assert not all(np.array_equal(shared[0], o) for o in shared[1:]), \
+        "sampled rows with distinct seeds should differ"
+
+
+@pytest.mark.parametrize("cache", ["off", "adaptive"])
+def test_refresh_counters_follow_the_phases(small_model, row_engines, cache):
+    """``refresh_passes`` counts steps with a row at FULL prompt refresh and
+    ``refresh_rows`` those rows.  ``_cfg`` refreshes at phases 0, 2, 4, 6
+    of each 8-step block; with the adaptive cache, those at 2 and 6 are
+    partial and run their own pass.  Two blocks per request."""
+    cfg, model, params = small_model
+    g, eng, _ = row_engines(cache)
+    per_block = 2 if cache == "adaptive" else 4
+    rng = np.random.default_rng(9)
+    prompts = [rng.integers(3, cfg.vocab_size, PROMPT_LEN).astype(np.int32)
+               for _ in range(3)]
+
+    def mk():
+        return [Request(prompt=p.copy(), sample_seed=i)
+                for i, p in enumerate(prompts)]
+    # one slot: the requests run one after another, a row per pass
+    one = StreamScheduler(model, params, g, max_slots=1,
+                          prompt_len=PROMPT_LEN, paged=True, page_size=PS,
+                          early_advance=True)
+    for r in mk():
+        one.submit(r)
+    one.drain()
+    assert one.stats.refresh_passes == 3 * 2 * per_block
+    assert one.stats.refresh_rows == 3 * 2 * per_block
+    assert one.stats.gauges()["refresh_rows_per_pass"] == 1.0
+    # SLOTS slots: two rows in step, the third a step behind them
+    _, three = _serve_staggered(model, params, g, mk(), 2, engine=eng)
+    assert three.stats.refresh_passes == 2 * 2 * per_block
+    assert three.stats.refresh_rows == 3 * 2 * per_block
+    assert three.stats.gauges()["refresh_rows_per_pass"] == 1.5
 
 
 # ---------------------------------------------------------------------------
@@ -219,6 +390,3 @@ def test_adaptive_cache_gating(small_model):
     with pytest.raises(AssertionError):
         DiffusionEngine(model, _cfg(skip_stages=(),
                                     cache_prompt_interval=2))
-    with pytest.raises(AssertionError):
-        DiffusionEngine(model, _cfg(cache_prompt_interval=2),
-                        gather_refresh=True)   # gather_refresh needs paged

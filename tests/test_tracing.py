@@ -136,3 +136,37 @@ def test_compiled_step_names_its_passes(small_model, adaptive):
         assert any(f"/{p}/" in n and "/es.attention/" in n for n in names), p
     # the K/V scatters stay outside the attention read
     assert not any("/es.attention/" in n and "scatter" in n for n in names)
+
+
+_SHAPE = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]")
+
+
+@pytest.mark.parametrize("paged", [True, False])
+def test_prompt_refresh_runs_on_its_rows(small_model, paged):
+    """A paged attention-only engine refreshes prompts one row at a time:
+    its one ``es.prompt_refresh`` conditional computes the prefill on
+    ``[1, T, d_model]`` activations.  A dense-KV engine keeps the masked
+    pass over every slot, ``[B, T, d_model]``."""
+    cfg, model, params = small_model
+    slots = 3
+    kw = dict(paged=True, page_size=PS) if paged else {}
+    sched = StreamScheduler(model, params, _cfg(), max_slots=slots,
+                            prompt_len=PROMPT_LEN, **kw)
+    for r in _requests(cfg, 2):
+        sched.submit(r)
+    sched.step()
+    eng = sched.engine
+    assert eng.refresh_per_row == paged
+    hlo = eng.compiled_step_text(sched.params, sched.state, sched._enc_out)
+    scoped = [ln for ln in hlo.splitlines()
+              if re.search(r'op_name="[^"]*/es\.prompt_refresh/', ln)]
+    conds = [ln for ln in scoped if " conditional(" in ln]
+    assert len(conds) == 1
+    assert re.search(r'op_name="[^"]*/es\.prompt_refresh/cond"', conds[0])
+    shapes = {m.group(1) for m in map(_SHAPE.match, scoped) if m}
+    t = PROMPT_LEN + GEN["gen_length"]
+    one, every = f"1,{t},{cfg.d_model}", f"{slots},{t},{cfg.d_model}"
+    if paged:
+        assert one in shapes and every not in shapes
+    else:
+        assert every in shapes and one not in shapes
