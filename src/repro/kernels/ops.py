@@ -169,7 +169,10 @@ def _attention_xla_chunked(q, k, v, q_pos, kv_pos, *, window, anchor, causal,
 
     Never materializes the [Lq, Lkv] score matrix, so prefill at 32k/500k
     lowers with O(Lq * kv_chunk) live memory — this is the HLO the dry-run
-    roofline reads.
+    roofline reads.  Grouped-query heads are folded into the query axis:
+    the ``group`` query heads of one KV head read its chunk as rows
+    ``[g * Lq + i]`` of a ``[B, Hkv, group * Lq, D]`` query, so the chunk
+    is never repeated per query head (a repeat that XLA materialises).
     """
     b, hq, lq, d = q.shape
     hkv, lkv = k.shape[1], k.shape[2]
@@ -192,13 +195,15 @@ def _attention_xla_chunked(q, k, v, q_pos, kv_pos, *, window, anchor, causal,
     else:
         kss = vss = jnp.zeros((n_chunks, 0), jnp.float32)   # placeholder xs
 
-    qf = q.astype(jnp.float32)
+    # query head j * group + g is row g * Lq + i of KV head j's query
+    lg = group * lq
+    qf = q.astype(jnp.float32).reshape(b, hkv, lg, d)
     # [n_chunks, B, Hkv, ck, D] etc. — scanned over axis 0
     ks = jnp.moveaxis(k.reshape(b, hkv, n_chunks, ck, d), 2, 0)
     vs = jnp.moveaxis(v.reshape(b, hkv, n_chunks, ck, d), 2, 0)
     ps = jnp.moveaxis(kv_pos.reshape(b, n_chunks, ck), 1, 0)
 
-    qp = q_pos[:, None, :, None]                       # [B,1,Lq,1]
+    qp = jnp.tile(q_pos, (1, group))[:, None, :, None]  # [B,1,group*Lq,1]
 
     def step(carry, inp):
         m_prev, l_prev, acc = carry
@@ -207,8 +212,8 @@ def _attention_xla_chunked(q, k, v, q_pos, kv_pos, *, window, anchor, causal,
             # dequantize inside the chunk: int8 rows never materialize wide
             kc = kc.astype(jnp.float32) * ksc[..., None]
             vc = vc.astype(jnp.float32) * vsc[..., None]
-        kc = jnp.repeat(kc, group, axis=1).astype(jnp.float32)
-        vc = jnp.repeat(vc, group, axis=1).astype(jnp.float32)
+        kc = kc.astype(jnp.float32)
+        vc = vc.astype(jnp.float32)
         s = jnp.einsum("bhqd,bhkd->bhqk", qf, kc) * scale
         kp_ = pc[:, None, None, :]
         mask = kp_ >= 0
@@ -235,15 +240,15 @@ def _attention_xla_chunked(q, k, v, q_pos, kv_pos, *, window, anchor, causal,
         return (m_new, l_new, acc), None
 
     init = (
-        jnp.full((b, hq, lq), NEG_INF, jnp.float32),
-        jnp.zeros((b, hq, lq), jnp.float32),
-        jnp.zeros((b, hq, lq, d), jnp.float32),
+        jnp.full((b, hkv, lg), NEG_INF, jnp.float32),
+        jnp.zeros((b, hkv, lg), jnp.float32),
+        jnp.zeros((b, hkv, lg, d), jnp.float32),
     )
     # checkpoint the chunk body: backward recomputes the [Lq, ck] score tile
     # instead of saving one per chunk (flash-attention recomputation)
     (m, l, acc), _ = jax.lax.scan(jax.checkpoint(step), init, (ks, vs, ps, kss, vss))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return out.astype(q.dtype)
+    return out.reshape(b, hq, lq, d).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -19,6 +19,8 @@ def attention_mask(
     window: int = 0,
     anchor: int = 0,
     causal: bool = False,
+    bc_start: int = 0,
+    bc_block: int = 0,
 ) -> jax.Array:
     """[B, Lq, Lkv] bool attention-allowed mask.
 
@@ -28,6 +30,10 @@ def attention_mask(
       - ``window > 0``: |q_pos - kv_pos| <= window, except kv_pos < anchor
         rows (prompt anchors) which are always attended (block-sparse
         long-context variant, DESIGN §5);
+      - ``bc_block > 0`` (block-causal): prompt positions (< ``bc_start``)
+        are block -1, position p >= bc_start is block
+        (p - bc_start) // bc_block; a query attends its own and earlier
+        blocks only;
       - default (window == 0, causal=False): full bidirectional (dLLM).
     """
     qp = q_pos[:, :, None]
@@ -40,6 +46,10 @@ def attention_mask(
         if anchor > 0:
             win |= kp < anchor
         mask &= win
+    if bc_block > 0:
+        qb = jnp.where(qp >= bc_start, (qp - bc_start) // bc_block, -1)
+        kb = jnp.where(kp >= bc_start, (kp - bc_start) // bc_block, -1)
+        mask &= kb <= qb
     return mask
 
 
@@ -53,6 +63,8 @@ def attention_reference(
     window: int = 0,
     anchor: int = 0,
     causal: bool = False,
+    bc_start: int = 0,
+    bc_block: int = 0,
     softmax_scale: float | None = None,
 ) -> jax.Array:
     """Naive rectangular GQA attention with materialized scores."""
@@ -65,7 +77,8 @@ def attention_reference(
     vv = jnp.repeat(v, group, axis=1)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32), kk.astype(jnp.float32))
     scores = scores * scale
-    mask = attention_mask(q_pos, kv_pos, window=window, anchor=anchor, causal=causal)
+    mask = attention_mask(q_pos, kv_pos, window=window, anchor=anchor,
+                          causal=causal, bc_start=bc_start, bc_block=bc_block)
     scores = jnp.where(mask[:, None, :, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     # rows where everything is masked: softmax of NEG_INF row is uniform; zero it

@@ -1193,10 +1193,15 @@ class StreamScheduler:
         the call; ``es.sched.admit``, ``es.sched.prepare``,
         ``es.engine.dispatch``, ``es.engine.wait``, ``es.sched.after``,
         ``es.sched.retire``, ``es.sched.grow`` inside it), which records
-        nothing unless a ``jax.profiler`` trace is running."""
-        args = {} if self.lane_index is None else {"lane": self.lane_index}
-        with _span("es.sched.step", **args):
+        nothing unless a ``jax.profiler`` trace is running.  A lane of
+        ``ShardedStreamScheduler`` gives its index as the ``lane``
+        argument of ``es.sched.step`` and of ``es.engine.wait``: which
+        lane's device the host is waiting on."""
+        with _span("es.sched.step", **self._lane_arg()):
             return self._step()
+
+    def _lane_arg(self) -> dict:
+        return {} if self.lane_index is None else {"lane": self.lane_index}
 
     def _step(self) -> bool:
         t0 = self.clock()           # admission work (incl. encode) is wall time
@@ -1264,7 +1269,7 @@ class StreamScheduler:
         with _span("es.engine.dispatch"):
             self.state = self.engine.step(self.params, self.state,
                                           self._enc_out)
-        with _span("es.engine.wait"):
+        with _span("es.engine.wait", **self._lane_arg()):
             jax.block_until_ready(self.state.tokens)
         self._step_count += 1
         dt = self.clock() - t0

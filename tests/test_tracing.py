@@ -2,8 +2,8 @@
 
 * One ``StreamScheduler.step`` under ``jax.profiler`` records the eight
   ``es.`` host spans, nested in ``es.sched.step`` and in order; a lane of
-  ``ShardedStreamScheduler`` gives its index as the span's ``lane``
-  argument.
+  ``ShardedStreamScheduler`` gives its index as the ``lane`` argument of
+  ``es.sched.step`` and of ``es.engine.wait``.
 * The compiled engine step carries ``es.skip_decode``, ``es.block_refresh``
   and ``es.prompt_refresh`` (and ``es.partial_refresh`` with the adaptive
   cache) in the ``op_name`` of its pass conditionals, and ``es.attention``
@@ -86,6 +86,8 @@ def test_step_records_host_spans_nested_in_order(small_model, tmp_path):
     assert [s[0] for s in spans] == ["es.sched.step"] + SCHED_SPANS
     step, children = spans[0], spans[1:]
     assert "lane" not in step[3]
+    wait, = [s for s in children if s[0] == "es.engine.wait"]
+    assert "lane" not in wait[3]
     for name, s, e, _ in children:
         assert step[1] <= s <= e <= step[2], name
     # siblings follow one another without overlap
@@ -105,8 +107,10 @@ def test_lane_index_is_a_span_argument(small_model, tmp_path):
     sched.step()
     with jax.profiler.trace(str(tmp_path)):
         sched.step()
-    steps = [s for s in _host_spans(str(tmp_path)) if s[0] == "es.sched.step"]
-    assert [s[3].get("lane") for s in steps] == [0, 1]
+    spans = _host_spans(str(tmp_path))
+    for name in ("es.sched.step", "es.engine.wait"):
+        got = [s[3].get("lane") for s in spans if s[0] == name]
+        assert got == [0, 1], name
 
 
 def _op_names(hlo: str) -> list[str]:
