@@ -348,6 +348,8 @@ def main() -> None:
             line += f"  deadline_rejects={server.stats.deadline_rejects}"
         if server.stats.poisoned_requests:
             line += f"  poisoned_requests={server.stats.poisoned_requests}"
+        if args.shards > 1:
+            line += f"  lanes_in_flight={server.stats.lanes_in_flight:.2f}"
     print(line)
     if args.runtime == "stream" and args.shards > 1:
         # per-shard gauge breakdown: placement + residency + pool usage of

@@ -40,6 +40,14 @@ Design contract (docs/ARCHITECTURE.md §6a):
   long prefill can no longer inflate decode p95 —
   ``benchmarks.costmodel.disagg_report`` gives the analytic bound.
 
+* **Lanes step together.**  One ``step()`` is a round: every lane with
+  work dispatches its engine step (``StreamScheduler._dispatch``), and
+  only then does the round wait on each lane and book its step
+  (``StreamScheduler._finish``), so the lanes' devices compute at once
+  and the host books lane k while the others compute.  Each lane's own
+  sequence (admit, dispatch, wait, book) is a lone scheduler's, so the
+  interleaving moves no output.
+
 All lanes share ONE ``DiffusionEngine`` (the scheduler's ``engine=``
 kwarg): homogeneous lanes reuse a single compiled step program, and
 disagg lanes retrace once per distinct state width — never per shard.
@@ -56,7 +64,7 @@ import numpy as np
 from repro.runtime.errors import ConfigError, DrainStalled, LedgerError
 from repro.runtime.request import Request, StreamCallback
 from repro.runtime.scheduler import PageAllocator, SchedulerStats, \
-    StreamScheduler
+    StreamScheduler, _span
 
 PLACEMENTS = ("least_loaded", "prefix_affinity", "disagg")
 
@@ -287,6 +295,11 @@ class ShardedStreamScheduler:
             [l.allocator for l in self.lanes]) if paged else None
         self.placements: dict[int, int] = {}    # request_id -> shard
         self.placed = [0] * shards              # per-shard admission counter
+        # rounds that dispatched a lane, the lanes they dispatched, and the
+        # wall of those rounds (the lanes' own walls overlap)
+        self.rounds = 0
+        self.lanes_dispatched = 0
+        self.wall_s = 0.0
 
     # ------------------------------------------------------------------
     # placement
@@ -328,11 +341,25 @@ class ShardedStreamScheduler:
         self.lanes[s].submit(req)
 
     def step(self) -> bool:
-        ran = False
+        """One round: dispatch every lane with work, then wait on and book
+        each lane that dispatched.  False when no lane ran a step."""
+        t0 = self.clock()
+        inflight = []
         for lane in self.lanes:
             if lane.has_work():
-                ran = lane.step() or ran
-        return ran
+                with _span("es.sched.step", lane=lane.lane_index):
+                    f = lane._dispatch()
+                if f is not None:
+                    inflight.append((lane, f))
+        if not inflight:
+            return False
+        self.rounds += 1
+        self.lanes_dispatched += len(inflight)
+        for lane, f in inflight:
+            with _span("es.sched.finish", lane=lane.lane_index):
+                lane._finish(f)
+        self.wall_s += self.clock() - t0
+        return True
 
     def has_work(self) -> bool:
         return any(lane.has_work() for lane in self.lanes)
@@ -391,9 +418,11 @@ class ShardedStreamScheduler:
     # ------------------------------------------------------------------
     @property
     def stats(self) -> SchedulerStats:
-        """Per-shard gauges rolled up additively (``wall_s`` sums the
-        per-lane engine-loop wall; peak gauges sum per-shard maxima — an
-        upper bound, since lane peaks need not co-occur)."""
+        """Per-shard gauges rolled up additively (peak gauges sum per-shard
+        maxima — an upper bound, since lane peaks need not co-occur).
+        ``wall_s``, ``rounds`` and ``lanes_dispatched`` are the router's
+        own: the lanes' walls overlap, so their sum would count a round's
+        seconds once per lane."""
         agg = SchedulerStats()
         for lane in self.lanes:
             for f in dataclasses.fields(SchedulerStats):
@@ -402,13 +431,19 @@ class ShardedStreamScheduler:
                     getattr(agg, f.name).extend(v)
                 else:
                     setattr(agg, f.name, getattr(agg, f.name) + v)
+        agg.wall_s = self.wall_s
+        agg.rounds = self.rounds
+        agg.lanes_dispatched = self.lanes_dispatched
         return agg
 
     def shard_gauges(self) -> list[dict]:
-        """Per-shard monitoring surface (the stats-line breakdown)."""
+        """Per-shard monitoring surface (the stats-line breakdown).
+        ``lanes_in_flight`` is the router's, the same on every row."""
+        lanes_in_flight = self.stats.lanes_in_flight
         out = []
         for s, lane in enumerate(self.lanes):
             g = lane.stats.gauges()
+            g["lanes_in_flight"] = lanes_in_flight
             g["shard"] = s
             g["placed"] = self.placed[s]
             g["resident"] = sum(r is not None for r in lane.slot_req)
@@ -420,6 +455,8 @@ class ShardedStreamScheduler:
     def reset_stats(self) -> None:
         """Bench idiom: zero every lane's counters after warmup, keeping
         the static pool gauge."""
+        self.rounds = self.lanes_dispatched = 0
+        self.wall_s = 0.0
         for lane in self.lanes:
             lane.stats.__init__()
             if lane.allocator is not None:

@@ -163,6 +163,10 @@ class SchedulerStats:
                                          # per-resume parked time (spill->resume)
     deadline_rejects: int = 0            # typed DeadlineUnmeetable verdicts
     poisoned_requests: int = 0           # rows quarantined by the NaN detector
+    # ShardedStreamScheduler's rounds (0 on a lone scheduler): rounds that
+    # dispatched a lane, and the lanes in flight when each began its waits
+    rounds: int = 0
+    lanes_dispatched: int = 0
 
     @property
     def goodput(self) -> float:
@@ -197,6 +201,13 @@ class SchedulerStats:
         return self.refresh_rows / self.refresh_passes
 
     @property
+    def lanes_in_flight(self) -> float:
+        """Mean lanes whose steps ran at once in a round (0.0 before any)."""
+        if not self.rounds:
+            return 0.0
+        return self.lanes_dispatched / self.rounds
+
+    @property
     def resume_p50(self) -> float:
         """Median seconds a preempted request spent parked on the host."""
         if not self.resume_waits:
@@ -229,6 +240,7 @@ class SchedulerStats:
             "resume_p50": self.resume_p50,
             "deadline_rejects": self.deadline_rejects,
             "poisoned_requests": self.poisoned_requests,
+            "lanes_in_flight": self.lanes_in_flight,
         }
 
     # BatchServer.stats compatibility
@@ -453,6 +465,20 @@ class _SpilledRequest:
     row: dict                # per-row counters + token/kv_valid/feat planes
     streamed: int            # blocks already streamed before the spill
     spill_s: float           # clock at spill (resume_waits gauge)
+
+
+@dataclasses.dataclass
+class _InFlight:
+    """A dispatched engine step: what its finish needs from its dispatch
+    (the step's start on the clock, the host copies read before the engine
+    ran)."""
+    t0: float
+    phases: np.ndarray
+    resident: np.ndarray
+    refresh_rows: np.ndarray
+    pre_blocks_left: np.ndarray
+    pre_r: Optional[np.ndarray] = None   # feature-cache counters (cache on)
+    pre_e: Optional[np.ndarray] = None
 
 
 class StreamScheduler:
@@ -1189,6 +1215,12 @@ class StreamScheduler:
         and advancement only happen when every slot wraps together), so the
         behavior reduces exactly to the old block-aligned scheduler.
 
+        The iteration is two halves run back to back: ``_dispatch`` (admit,
+        prepare, enqueue the engine step) and ``_finish`` (wait on the
+        device, then book the step).  ``ShardedStreamScheduler`` runs every
+        lane's first half before any lane's second, so the lanes' devices
+        compute at once.
+
         Each phase runs inside a profiler span (``es.sched.step`` around
         the call; ``es.sched.admit``, ``es.sched.prepare``,
         ``es.engine.dispatch``, ``es.engine.wait``, ``es.sched.after``,
@@ -1196,14 +1228,21 @@ class StreamScheduler:
         nothing unless a ``jax.profiler`` trace is running.  A lane of
         ``ShardedStreamScheduler`` gives its index as the ``lane``
         argument of ``es.sched.step`` and of ``es.engine.wait``: which
-        lane's device the host is waiting on."""
+        lane's device the host is waiting on.  There ``es.sched.step``
+        covers the dispatch half and ``es.sched.finish`` the finish half."""
         with _span("es.sched.step", **self._lane_arg()):
-            return self._step()
+            inflight = self._dispatch()
+            if inflight is None:
+                return False
+            self._finish(inflight)
+            return True
 
     def _lane_arg(self) -> dict:
         return {} if self.lane_index is None else {"lane": self.lane_index}
 
-    def _step(self) -> bool:
+    def _dispatch(self) -> Optional[_InFlight]:
+        """Admit, prepare and enqueue one engine step without waiting on
+        the device; None when nothing is resident (no step runs)."""
         t0 = self.clock()           # admission work (incl. encode) is wall time
         with _span("es.sched.admit"):
             phases = np.asarray(self.state.phase)
@@ -1223,7 +1262,7 @@ class StreamScheduler:
                 phases = np.asarray(self.state.phase)
         resident = np.asarray([r is not None for r in self.slot_req])
         if not resident.any():
-            return False
+            return None
         with _span("es.sched.prepare"):
             # rows whose upcoming step is a prompt refresh — the only branch
             # that scatters into THAT row's prompt pages — per the engine's
@@ -1259,28 +1298,36 @@ class StreamScheduler:
                         inv - np.asarray(self.state.prompt_start), 0)
                     self.stats.invariant_tokens_skipped += int(
                         skipped[full_r].sum())
-            pre_blocks_left = np.asarray(self.state.blocks_left)
-            track_cache = self.state.feat is not None
-            if track_cache:
+            inflight = _InFlight(t0, phases, resident, refresh_rows,
+                                 np.asarray(self.state.blocks_left))
+            if self.state.feat is not None:
                 # cumulative per-slot counters (reset on admission): the step
                 # delta is this iteration's refresh activity
-                pre_r = np.asarray(self.state.cache_refreshed)
-                pre_e = np.asarray(self.state.cache_eligible)
+                inflight.pre_r = np.asarray(self.state.cache_refreshed)
+                inflight.pre_e = np.asarray(self.state.cache_eligible)
         with _span("es.engine.dispatch"):
             self.state = self.engine.step(self.params, self.state,
                                           self._enc_out)
+        return inflight
+
+    def _finish(self, inflight: _InFlight) -> None:
+        """Wait on the step ``_dispatch`` enqueued, then book it: the wall
+        and its EWMA, cache counters, quarantine, reclaim, retirement and
+        window growth."""
+        phases, resident = inflight.phases, inflight.resident
+        refresh_rows = inflight.refresh_rows
         with _span("es.engine.wait", **self._lane_arg()):
             jax.block_until_ready(self.state.tokens)
         self._step_count += 1
-        dt = self.clock() - t0
+        dt = self.clock() - inflight.t0
         self.stats.wall_s += dt
         # per-step wall EWMA: the measured-cost term of deadline admission
         self._step_ewma = dt if self._step_ewma is None \
             else 0.8 * self._step_ewma + 0.2 * dt
         with _span("es.sched.after"):
-            if track_cache:
-                d_r = np.asarray(self.state.cache_refreshed) - pre_r
-                d_e = np.asarray(self.state.cache_eligible) - pre_e
+            if inflight.pre_r is not None:
+                d_r = np.asarray(self.state.cache_refreshed) - inflight.pre_r
+                d_e = np.asarray(self.state.cache_eligible) - inflight.pre_e
                 self.stats.cache_refreshed_total += int(d_r.sum())
                 self.stats.cache_eligible_total += int(d_e.sum())
                 self.stats.refresh_event_tokens.extend(d_r[d_e > 0].tolist())
@@ -1295,8 +1342,8 @@ class StreamScheduler:
                 self._reclaim_dead_pages(refresh_rows)
         with _span("es.sched.retire"):
             if self.early_advance:
-                adv = (np.asarray(self.state.blocks_left) < pre_blocks_left) \
-                    & resident
+                adv = (np.asarray(self.state.blocks_left)
+                       < inflight.pre_blocks_left) & resident
                 steps_pb = self.gen.resolved_steps()
                 self.stats.early_advances += int(
                     (adv & ((phases + 1) % steps_pb != 0)).sum())
@@ -1312,7 +1359,6 @@ class StreamScheduler:
             # the phase wrap, not through the early_advance bookkeeping
             with _span("es.sched.grow"):
                 self._grow_windows()
-        return True
 
     # ------------------------------------------------------------------
     # lazy reservation: just-in-time window growth

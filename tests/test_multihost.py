@@ -18,7 +18,10 @@ Contract under test (docs/ARCHITECTURE.md §6a):
     the dry-run trick) pins one lane per fake device and supports the
     jit-with-shardings step (``bind_state_shardings`` over
     ``make_host_mesh``) — exercised in a subprocess so the XLA flag is
-    set before jax initialises.
+    set before jax initialises;
+  * one ``step()`` dispatches every lane before it waits on any, and that
+    interleaving moves nothing: placements, outputs, step counts and
+    ledgers equal those of the lanes stepped one at a time.
 """
 import dataclasses
 import importlib.util
@@ -53,13 +56,17 @@ PS = 8
 GEN = dict(gen_length=32, block_length=8)       # 4 blocks; t_total = 48
 
 
-@pytest.fixture(scope="module")
-def small_model():
+def _small_model():
     cfg = configs.reduced(configs.get_config("llada-8b"))
     cfg = dataclasses.replace(cfg, n_layers=4)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     return cfg, model, params
+
+
+@pytest.fixture(scope="module")
+def small_model():
+    return _small_model()
 
 
 def _cfg(**kw):
@@ -277,6 +284,150 @@ def test_stats_rollup_and_shard_gauges(small_model):
     assert sched.stats.completed == 0
     assert sched.stats.pages_total == sched.allocator.num_pages - len(
         sched.lanes)
+
+
+def test_stats_wall_is_the_rounds_wall_and_counts_lanes_in_flight(
+        small_model):
+    """The rollup's ``wall_s`` is the router's own wall, not the sum of the
+    overlapping lane walls: it fits inside the drain's real wall.  Two
+    equal lanes are both in flight in every round."""
+    cfg, model, params = small_model
+    sched = _sharded(model, params, _cfg())
+    for r in _requests(cfg, 4):
+        sched.submit(r)
+    assert sched.placed == [2, 2]
+    t0 = sched.clock()
+    sched.drain()
+    wall = sched.clock() - t0
+    agg = sched.stats
+    assert 0.0 < agg.wall_s <= wall
+    assert agg.rounds == sched.lanes[0]._step_count > 0
+    assert agg.lanes_in_flight == 2.0
+    assert agg.gauges()["lanes_in_flight"] == 2.0
+    assert [g["lanes_in_flight"] for g in sched.shard_gauges()] == [2.0, 2.0]
+    sched.reset_stats()
+    assert sched.stats.wall_s == 0.0 and sched.stats.lanes_in_flight == 0.0
+
+
+# ---------------------------------------------------------------------------
+# the round: every lane dispatched before the first wait
+# ---------------------------------------------------------------------------
+
+
+def test_step_dispatches_every_lane_before_the_first_wait(small_model,
+                                                          monkeypatch):
+    """One ``step()`` enqueues lane 0's and lane 1's engine steps before it
+    blocks on either device, then waits on them in lane order."""
+    cfg, model, params = small_model
+    sched = _sharded(model, params, _cfg())
+    for r in _requests(cfg, 4):
+        sched.submit(r)
+    calls = []
+    engine_step = sched.engine.step
+
+    def lane_of(pick, x):
+        return [i for i, l in enumerate(sched.lanes) if pick(l) is x]
+
+    def step(params, state, enc_out):
+        calls.append(("dispatch", *lane_of(lambda l: l.state, state)))
+        return engine_step(params, state, enc_out)
+
+    block = jax.block_until_ready
+
+    def wait(x):
+        calls.append(("wait", *lane_of(lambda l: l.state.tokens, x)))
+        return block(x)
+
+    monkeypatch.setattr(sched.engine, "step", step)
+    monkeypatch.setattr(jax, "block_until_ready", wait)
+    for _ in range(2):
+        calls.clear()
+        assert sched.step()
+        assert calls == [("dispatch", 0), ("dispatch", 1),
+                         ("wait", 0), ("wait", 1)], calls
+
+
+def _lanes_one_at_a_time(sched):
+    """The lanes stepped in turn, each to its own wait (no overlap)."""
+    while sched.has_work():
+        for lane in sched.lanes:
+            if lane.has_work():
+                lane.step()
+    return sched.completed
+
+
+def check_overlap_equivalence(cfg, model, params, temperature, devices):
+    """The same requests through overlapped rounds and through the lanes
+    stepped one at a time: equal placements, outputs and step counts, and
+    both ledgers conserved and empty."""
+    g = _cfg(temperature=temperature)
+    runs = []
+    for drive in (lambda s: s.drain(), _lanes_one_at_a_time):
+        sched = _sharded(model, params, g, devices=devices)
+        if devices is not None:
+            assert len(set(sched.devices)) == sched.shards
+        reqs = _requests(cfg, 6)
+        for r in reqs:
+            sched.submit(r)
+        done = drive(sched)
+        assert len(done) == len(reqs)
+        assert all(r.error is None for r in done)
+        for lane in sched.lanes:
+            fuzz.check_allocator_invariants(lane)
+        sched.allocator.check_conservation()
+        assert sched.allocator.used_pages == 0
+        runs.append((dict(sched.placements),
+                     {r.request_id: r.output for r in done},
+                     [lane._step_count for lane in sched.lanes]))
+    (pl_a, out_a, n_a), (pl_b, out_b, n_b) = runs
+    assert pl_a == pl_b
+    assert n_a == n_b
+    assert out_a.keys() == out_b.keys()
+    for rid in out_a:
+        np.testing.assert_array_equal(out_a[rid], out_b[rid],
+                                      err_msg=f"request {rid}")
+
+
+_EQUIV_SUBPROC = r"""
+import os
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=2")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+import sys
+import jax
+assert len(jax.devices()) == 2, jax.devices()
+import test_multihost as t
+cfg, model, params = t._small_model()
+t.check_overlap_equivalence(cfg, model, params, float(sys.argv[1]),
+                            jax.devices())
+print("EQUIV_OK")
+"""
+
+
+def _subprocess_env() -> dict:
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(__file__), "..", "src"),
+         os.path.dirname(os.path.abspath(__file__)),
+         env.get("PYTHONPATH", "")])
+    return env
+
+
+@pytest.mark.parametrize("devices", ["shared", "forced2"])
+@pytest.mark.parametrize("temperature", [0.0, 0.7])
+def test_overlapped_rounds_equal_lanes_stepped_in_turn(small_model,
+                                                       temperature, devices):
+    """Overlapping the lanes moves no output, on lanes that share a device
+    and on lanes pinned to two forced CPU devices (a subprocess, so the
+    XLA flag is set before jax initialises)."""
+    if devices == "shared":
+        check_overlap_equivalence(*small_model, temperature, None)
+        return
+    proc = subprocess.run(
+        [sys.executable, "-c", _EQUIV_SUBPROC, str(temperature)],
+        env=_subprocess_env(), capture_output=True, text=True, timeout=900)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    assert "EQUIV_OK" in proc.stdout
 
 
 # ---------------------------------------------------------------------------
