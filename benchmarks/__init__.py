@@ -1,2 +1,2 @@
-# Benchmark harness: one module per paper table/figure + roofline reporter.
-# Run everything: PYTHONPATH=src python -m benchmarks.run
+# Analytic cost model (costmodel.py) and the dry-run roofline reporter
+# (roofline.py): PYTHONPATH=src python -m benchmarks.roofline

@@ -15,7 +15,6 @@ f32 (4) for optimizer moments.
 from __future__ import annotations
 
 import dataclasses
-import math
 
 from repro.configs.base import GenerationConfig, InputShape, ModelConfig
 from repro.core.schedule import resolve_segments
@@ -149,199 +148,6 @@ def kv_cache_bytes(cfg: ModelConfig, batch: int, seq: int) -> float:
     n_attn = sum(1 for l in range(cfg.n_layers)
                  if cfg.layer_kind(l) in ("attn", "selfcross"))
     return 2 * n_attn * batch * seq * cfg.n_kv_heads * cfg.head_dim * BF16
-
-
-def kv_bytes_per_decode_iter(cfg: ModelConfig, kv_tokens: float, *,
-                             quantized: bool = False) -> float:
-    """HBM bytes of KV rows streamed through flash attention in ONE decode
-    iteration, given the total number of *attended* cache tokens across the
-    batch.
-
-    This is the term the paged layout shrinks: dense serving drags
-    ``slots * (prompt_len + gen_length)`` rows through the kernel every
-    iteration regardless of each request's real extent, while the paged
-    kernel walks only *mapped* pages — ``pages_in_use * page_size`` rows
-    (unmapped block-table entries repeat the garbage page, whose re-fetch
-    the pipeline elides)."""
-    n_attn = sum(1 for l in range(cfg.n_layers)
-                 if cfg.layer_kind(l) in ("attn", "selfcross"))
-    per_row = cfg.n_kv_heads * cfg.head_dim * (1 if quantized else BF16)
-    if quantized:
-        per_row += cfg.n_kv_heads * F32          # dequant scale planes
-    return 2 * n_attn * kv_tokens * per_row
-
-
-def serving_kv_report(cfg: ModelConfig, *, slots_dense: int, t_total: int,
-                      paged_tokens_mean: float, pool_pages: int,
-                      page_size: int, quantized: bool = False) -> dict:
-    """Dense-vs-paged KV traffic + capacity summary for the bench JSON."""
-    dense_iter = kv_bytes_per_decode_iter(
-        cfg, slots_dense * t_total, quantized=quantized)
-    paged_iter = kv_bytes_per_decode_iter(
-        cfg, paged_tokens_mean, quantized=quantized)
-    return {
-        "dense_kv_bytes_per_iter": dense_iter,
-        "paged_kv_bytes_per_iter": paged_iter,
-        "kv_bytes_ratio": dense_iter / max(paged_iter, 1.0),
-        "dense_pool_bytes": kv_cache_bytes(cfg, slots_dense, t_total),
-        "paged_pool_bytes": kv_cache_bytes(cfg, 1, pool_pages * page_size),
-    }
-
-
-def prefix_sharing_report(cfg: ModelConfig, *, pool_pages: int,
-                          page_size: int, req_pages: int,
-                          shared_pages: int) -> dict:
-    """Analytic admitted-concurrency bound for a duplicate-prefix burst.
-
-    Unshared, every request costs ``req_pages``; with prefix sharing the
-    cohort owner pays ``req_pages`` once and every follower only its private
-    ``req_pages - shared_pages``.  The ratio of the two bounds is the
-    capacity headroom CoW sharing buys at EQUAL pool bytes — the number the
-    serving benchmark's measured ``resident_peak`` should approach."""
-    private = req_pages - shared_pages
-    unshared = pool_pages // req_pages
-    shared = 0 if pool_pages < req_pages else \
-        1 + (pool_pages - req_pages) // max(private, 1)
-    page_bytes = kv_cache_bytes(cfg, 1, page_size)
-    return {
-        "bound_unshared": unshared,
-        "bound_shared": shared,
-        "bound_gain": shared / max(unshared, 1),
-        "page_bytes": page_bytes,
-        "bytes_saved_per_follower": shared_pages * page_bytes,
-    }
-
-
-def prefix_persist_report(cfg: ModelConfig, *, pool_pages: int,
-                          page_size: int, req_pages: int,
-                          shared_pages: int) -> dict:
-    """Analytic bounds for the persistent cross-request prefix store.
-
-    Without sharing every resident costs its full ``req_pages`` extent;
-    with a warm persistent store the prompt's ``shared_pages`` are paid
-    ONCE (they stay resident across admission cycles), and every request —
-    including the first of a wave — maps them read-only and allocates only
-    its private ``req_pages - shared_pages``.  The concurrency ratio at
-    EQUAL pool bytes is what the serving benchmark's warm wave should
-    approach; ``bytes_resident`` is the standing cost of keeping the
-    prefix warm between waves."""
-    private = req_pages - shared_pages
-    unshared = pool_pages // req_pages
-    warm = (pool_pages - shared_pages) // max(private, 1)
-    page_bytes = kv_cache_bytes(cfg, 1, page_size)
-    return {
-        "bound_unshared": unshared,
-        "bound_warm": warm,
-        "bound_gain": warm / max(unshared, 1),
-        "page_bytes": page_bytes,
-        "bytes_resident": shared_pages * page_bytes,
-        "bytes_saved_per_request": shared_pages * page_bytes,
-    }
-
-
-def suffix_window_report(cfg: ModelConfig, gen: GenerationConfig, *,
-                         pool_pages: int, page_size: int,
-                         prompt_len: int) -> dict:
-    """Analytic admission/compute bounds for lazy reservation + the sliding
-    active window (Streaming-dLLM suffix pruning).
-
-    Pages: a full-prompt request's whole extent spans ``pages_full`` pool
-    pages; lazy admission maps only prompt + one active window
-    (``pages_admit``) and defers the rest (``pages_deferred`` each).  The
-    no-deadlock reserve policy keeps the free list covering one max deficit,
-    so at EQUAL pool bytes the steady-state concurrency bounds are
-    ``pool // pages_full`` (eager) vs ``(pool - deficit) // pages_admit``
-    (lazy) — their ratio is the capacity headroom the serving benchmark's
-    measured ``resident_peak`` should approach.
-
-    Compute: the window caps every block's attended KV length at
-    ``bs + block_length * (1 + window_blocks)`` instead of the full
-    ``t_total``, so per-iteration attention score FLOPs (and streamed KV
-    bytes) scale with the window, not ``gen_length``.  Reported per request
-    as the mean over its blocks — the measured bench section asserts
-    against these exact numbers."""
-    assert gen.windowed, "suffix_window_report needs window_blocks > 0"
-    lb = gen.block_length
-    n_blocks = gen.gen_length // lb
-    t_total = prompt_len + gen.gen_length
-    pages_full = -(-t_total // page_size)
-    init_blocks = min(1 + gen.window_blocks, n_blocks)
-    pages_admit = -(-(prompt_len + init_blocks * lb) // page_size)
-    deficit = pages_full - pages_admit
-    bound_full = pool_pages // pages_full
-    bound_lazy = max((pool_pages - deficit) // pages_admit, 0)
-    n_attn = sum(1 for l in range(cfg.n_layers)
-                 if cfg.layer_kind(l) in ("attn", "selfcross"))
-    kv_full = [t_total] * n_blocks
-    kv_win = [min(prompt_len + (i + 1 + gen.window_blocks) * lb, t_total)
-              for i in range(n_blocks)]
-    flops = lambda kv: lb * n_attn * sum(
-        _attn_score_flops(cfg, k) for k in kv) / n_blocks
-    return {
-        "pages_full": pages_full,
-        "pages_admit": pages_admit,
-        "pages_deferred": deficit,
-        "bound_full": bound_full,
-        "bound_lazy": bound_lazy,
-        "bound_gain": bound_lazy / max(bound_full, 1),
-        "attn_flops_per_iter_full": flops(kv_full),
-        "attn_flops_per_iter_windowed": flops(kv_win),
-        "attn_flops_ratio": flops(kv_full) / max(flops(kv_win), 1.0),
-        "kv_bytes_per_iter_full": kv_bytes_per_decode_iter(
-            cfg, sum(kv_full) / n_blocks),
-        "kv_bytes_per_iter_windowed": kv_bytes_per_decode_iter(
-            cfg, sum(kv_win) / n_blocks),
-    }
-
-
-def disagg_report(cfg: ModelConfig, gen: GenerationConfig, *,
-                  prompt_len: int, decode_prompt_len: int,
-                  slots_per_shard: int, n_long: int, n_short: int,
-                  mesh_axes: dict | None = None) -> dict:
-    """Analytic bound for prefill/decode disaggregation (dInfer smoothing).
-
-    Every dLLM iteration reprocesses context, so the jitted step's width is
-    the scheduler's padded ``prompt_len + gen_length`` for EVERY co-resident
-    row: one long prompt in the batch inflates each decode iteration of
-    every short request sharing the scheduler.  Disaggregation pins long
-    prompts to ``refresh`` shards (full ``prompt_len``) and pads the
-    ``decode`` shards to ``decode_prompt_len`` only.
-
-    ``decode_iter_gain`` is the per-iteration work ratio of a decode step
-    at the mixed (long-padded) width vs the disaggregated (short-padded)
-    width — the analytic CEILING on the decode p95 improvement the serving
-    benchmark can measure (wall-clock gains sit below it on small models,
-    where fixed dispatch overhead dilutes the width term, and above it only
-    through queueing effects the iteration model does not count, i.e. short
-    rows stuck behind a long refresh).  ``refresh_displacement`` counts how
-    many short-width decode iterations ONE long prompt refresh displaces —
-    the head-of-line term the mixed deployment adds to decode p95 and the
-    disaggregated one removes.  ``placement`` is the routing split the
-    ``disagg`` policy must produce on the given trace (long prompts to the
-    refresh shards, short to the decode shards) — the bench asserts the
-    measured split EXACTLY."""
-    mesh_axes = mesh_axes or {}
-    shape_long = InputShape("disagg_long", prompt_len + gen.gen_length,
-                            slots_per_shard, "decode")
-    shape_short = InputShape("disagg_short",
-                             decode_prompt_len + gen.gen_length,
-                             slots_per_shard, "decode")
-    mixed = decode_step_cost(cfg, shape_long, gen, mesh_axes)
-    disagg = decode_step_cost(cfg, shape_short, gen, mesh_axes)
-    refresh = prefill_cost(
-        cfg, InputShape("disagg_refresh", prompt_len + gen.gen_length,
-                        1, "prefill"),
-        gen, mesh_axes)
-    return {
-        "t_total_long": prompt_len + gen.gen_length,
-        "t_total_short": decode_prompt_len + gen.gen_length,
-        "decode_iter_flops_mixed": mixed.flops,
-        "decode_iter_flops_disagg": disagg.flops,
-        "decode_iter_gain": mixed.flops / max(disagg.flops, 1.0),
-        "refresh_flops": refresh.flops,
-        "refresh_displacement": refresh.flops / max(disagg.flops, 1.0),
-        "placement": {"refresh": n_long, "decode": n_short},
-    }
 
 
 # ---------------------------------------------------------------------------

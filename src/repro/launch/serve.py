@@ -1,13 +1,13 @@
 """Serving launcher: run the ES-dLLM serving runtime on a reduced model
 (CPU-runnable end-to-end driver, deliverable b).
 
-Two runtimes:
-  * ``stream`` (default) — continuous batching: slot admission at block
-    boundaries, slot recycling on completion, per-request block streaming.
-    ``--paged`` turns the KV caches into one shared page pool; add
-    ``--prefix-sharing`` (and e.g. ``--dup-prompts``) for copy-on-write
-    prompt-page dedup across duplicate requests (docs/ARCHITECTURE.md).
-  * ``batch``  — the lock-step micro-batching baseline (paper §6.1 setting).
+The runtime is the continuous-batching ``StreamScheduler`` (one per lane
+of a ``ShardedStreamScheduler`` with ``--shards > 1``): slot admission at
+block boundaries, slot recycling on completion, per-request block
+streaming.  ``--paged`` turns the KV caches
+into one shared page pool; add ``--prefix-sharing`` (and e.g.
+``--dup-prompts``) for copy-on-write prompt-page dedup across duplicate
+requests (docs/ARCHITECTURE.md).
 
   PYTHONPATH=src python -m repro.launch.serve --arch llada-8b --requests 16
   PYTHONPATH=src python -m repro.launch.serve --paged --prefix-sharing \
@@ -29,8 +29,8 @@ import numpy as np
 from repro import configs
 from repro.configs import GenerationConfig, ModelConfig, default_skip_stages
 from repro.models import build_model
-from repro.runtime import (BatchServer, ConfigError, Request,
-                           ShardedStreamScheduler, StreamScheduler)
+from repro.runtime import (ConfigError, Request, ShardedStreamScheduler,
+                           StreamScheduler)
 
 REPO_ROOT = Path(__file__).resolve().parents[3]
 
@@ -54,10 +54,9 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--full", action="store_true",
                     help="full config (default: reduced, CPU-runnable)")
     ap.add_argument("--mode", default="es", choices=["vanilla", "dualcache", "es"])
-    ap.add_argument("--runtime", default="stream", choices=["stream", "batch"])
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--batch", type=int, default=8,
-                    help="batch size (lock-step) / slot count (stream)")
+                    help="slot count (split evenly across --shards)")
     ap.add_argument("--gen-length", type=int, default=32)
     ap.add_argument("--block-length", type=int, default=16)
     ap.add_argument("--prompt-len", type=int, default=32)
@@ -65,13 +64,12 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--early-advance", action="store_true",
                     help="per-row cadence: a slot advances its block the "
                          "moment it fully unmasks and admission happens on "
-                         "any iteration (stream runtime only; pairs with "
-                         "--parallel-decoding, which makes block completion "
-                         "time variable)")
+                         "any iteration (pairs with --parallel-decoding, "
+                         "which makes block completion time variable)")
     ap.add_argument("--stream-print", action="store_true",
                     help="print each request's blocks as they unmask")
     ap.add_argument("--paged", action="store_true",
-                    help="paged KV pool + block tables (stream runtime only)")
+                    help="paged KV pool + block tables")
     ap.add_argument("--page-size", type=int, default=16)
     ap.add_argument("--kv-pages", type=int, default=None,
                     help="pool pages incl. garbage page (default: dense-equivalent)")
@@ -109,12 +107,11 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--priority-classes", type=int, default=1,
                     help="spread requests round-robin over this many "
                          "admission classes (class k = priority k; higher "
-                         "admits first, stream runtime only)")
+                         "admits first)")
     ap.add_argument("--deadline-s", type=float, default=None,
                     help="per-request SLO budget from arrival; admission "
                          "rejects a request with a typed DeadlineUnmeetable "
-                         "once wait + estimated service exceeds it "
-                         "(stream runtime only)")
+                         "once wait + estimated service exceeds it")
     ap.add_argument("--preemption", action="store_true",
                     help="priority preemption with host page spill/resume: "
                          "a higher-class arrival may spill a lower-class "
@@ -132,7 +129,7 @@ def build_parser() -> argparse.ArgumentParser:
                          "private slot plane, page ledger, and admission "
                          "queue; a global placement policy routes each "
                          "request to exactly one shard (requires --paged; "
-                         "stream runtime only; docs/ARCHITECTURE.md §6a)")
+                         "docs/ARCHITECTURE.md §6a)")
     ap.add_argument("--placement", default="least_loaded",
                     choices=["least_loaded", "prefix_affinity", "disagg"],
                     help="per-request shard placement policy: least_loaded "
@@ -154,8 +151,8 @@ def build_parser() -> argparse.ArgumentParser:
 def validate(args: argparse.Namespace) -> None:
     """Typed upfront checks of a parsed command line."""
     # fail fast on SLO/preemption misconfiguration, before any model build
-    # (the scheduler re-validates --preemption, but the batch runtime never
-    # reaches it, and a bad flag should not cost a params init)
+    # (the scheduler re-validates --preemption, but a bad flag should not
+    # cost a params init)
     if args.priority_classes < 1:
         raise ConfigError(
             f"--priority-classes must be >= 1, got {args.priority_classes}")
@@ -163,12 +160,6 @@ def validate(args: argparse.Namespace) -> None:
         raise ConfigError(
             f"--deadline-s must be positive, got {args.deadline_s} "
             "(a non-positive budget rejects every request at submit)")
-    if args.runtime == "batch" and (args.preemption
-                                    or args.priority_classes > 1
-                                    or args.deadline_s is not None):
-        raise ConfigError(
-            "--preemption/--priority-classes/--deadline-s need the stream "
-            "runtime: the lock-step batch server has no admission policy")
     if args.preemption and not args.paged:
         raise ConfigError("--preemption requires --paged: spilling moves "
                           "pool pages, dense KV rows cannot be released")
@@ -185,10 +176,6 @@ def validate(args: argparse.Namespace) -> None:
     if args.shards < 1:
         raise ConfigError(f"--shards must be >= 1, got {args.shards}")
     if args.shards > 1:
-        if args.runtime != "stream":
-            raise ConfigError("--shards > 1 needs the stream runtime: the "
-                              "lock-step batch server has no page ledger "
-                              "to shard")
         if not args.paged:
             raise ConfigError("--shards > 1 requires --paged: shards own "
                               "per-shard page ledgers")
@@ -252,10 +239,10 @@ def generation_config(args: argparse.Namespace,
 
 def build_server(args: argparse.Namespace, model, params,
                  gen: GenerationConfig, stream_cb=None, **engine_kw):
-    """The server the command line describes: a sharded or single
-    ``StreamScheduler``, or the lock-step ``BatchServer``.  ``engine_kw``
+    """The server the command line describes: a ``ShardedStreamScheduler``
+    with ``--shards > 1``, else a ``StreamScheduler``.  ``engine_kw``
     reaches the ``DiffusionEngine`` (e.g. ``attn_impl``)."""
-    if args.runtime == "stream" and args.shards > 1:
+    if args.shards > 1:
         return ShardedStreamScheduler(
             model, params, gen, shards=args.shards,
             placement=args.placement, refresh_shards=args.refresh_shards,
@@ -267,17 +254,14 @@ def build_server(args: argparse.Namespace, model, params,
             early_advance=args.early_advance,
             lazy_reserve=args.lazy_reserve, preemption=args.preemption,
             **engine_kw)
-    if args.runtime == "stream":
-        return StreamScheduler(model, params, gen, max_slots=args.batch,
-                               prompt_len=args.prompt_len, stream_cb=stream_cb,
-                               paged=args.paged, page_size=args.page_size,
-                               kv_pages=args.kv_pages,
-                               prefix_sharing=args.prefix_sharing,
-                               early_advance=args.early_advance,
-                               lazy_reserve=args.lazy_reserve,
-                               preemption=args.preemption, **engine_kw)
-    return BatchServer(model, params, gen, batch_size=args.batch,
-                       prompt_len=args.prompt_len, **engine_kw)
+    return StreamScheduler(model, params, gen, max_slots=args.batch,
+                           prompt_len=args.prompt_len, stream_cb=stream_cb,
+                           paged=args.paged, page_size=args.page_size,
+                           kv_pages=args.kv_pages,
+                           prefix_sharing=args.prefix_sharing,
+                           early_advance=args.early_advance,
+                           lazy_reserve=args.lazy_reserve,
+                           preemption=args.preemption, **engine_kw)
 
 
 def main() -> None:
@@ -312,46 +296,45 @@ def main() -> None:
             **slo))
 
     done = server.drain()
-    line = (f"served {len(done)} requests  runtime={args.runtime}  "
+    line = (f"served {len(done)} requests  "
             f"mode={args.mode}  TPS={server.stats.tps:.2f}  "
-            f"wall={server.stats.wall_s:.2f}s")
-    if args.runtime == "stream":
-        line += (f"  p50={server.stats.latency_pct(50):.2f}s"
-                 f"  p95={server.stats.latency_pct(95):.2f}s"
-                 f"  admission_p50={server.stats.admission_wait_p50:.3f}s")
-        if args.early_advance:
-            line += f"  early_advances={server.stats.early_advances}"
-        if gen.adaptive_cache:
-            line += (f"  cache_hit={server.stats.cache_hit_fraction:.3f}"
-                     f"  refresh_p50={server.stats.tokens_refreshed_p50:.0f}")
-        if args.paged:
-            line += (f"  peak_pages={server.stats.peak_pages_in_use}"
-                     f"/{server.stats.pages_total}"
-                     f"  concurrency_peak={server.stats.resident_peak}")
-            if args.prefix_sharing:
-                line += f"  cow_forks={server.stats.cow_forks}"
-            persistent = (any(l.persistent_prefix for l in server.lanes)
-                          if args.shards > 1 else server.persistent_prefix)
-            if persistent:
-                line += (f"  prefix_hits={server.stats.prefix_hits}"
-                         f"  prefix_evictions={server.stats.prefix_evictions}")
-            if gen.sparse_attention:
-                line += f"  pages_reclaimed={server.stats.pages_reclaimed}"
-            if args.lazy_reserve:
-                line += (f"  pages_deferred={server.stats.pages_deferred}"
-                         f"  window_stalls={server.stats.window_stalls}")
-        if args.preemption:
-            line += (f"  preemptions={server.stats.preemptions}"
-                     f"  pages_spilled={server.stats.pages_spilled}"
-                     f"  resume_p50={server.stats.resume_p50:.3f}s")
-        if args.deadline_s is not None:
-            line += f"  deadline_rejects={server.stats.deadline_rejects}"
-        if server.stats.poisoned_requests:
-            line += f"  poisoned_requests={server.stats.poisoned_requests}"
-        if args.shards > 1:
-            line += f"  lanes_in_flight={server.stats.lanes_in_flight:.2f}"
+            f"wall={server.stats.wall_s:.2f}s"
+            f"  p50={server.stats.latency_pct(50):.2f}s"
+            f"  p95={server.stats.latency_pct(95):.2f}s"
+            f"  admission_p50={server.stats.admission_wait_p50:.3f}s")
+    if args.early_advance:
+        line += f"  early_advances={server.stats.early_advances}"
+    if gen.adaptive_cache:
+        line += (f"  cache_hit={server.stats.cache_hit_fraction:.3f}"
+                 f"  refresh_p50={server.stats.tokens_refreshed_p50:.0f}")
+    if args.paged:
+        line += (f"  peak_pages={server.stats.peak_pages_in_use}"
+                 f"/{server.stats.pages_total}"
+                 f"  concurrency_peak={server.stats.resident_peak}")
+        if args.prefix_sharing:
+            line += f"  cow_forks={server.stats.cow_forks}"
+        persistent = (any(l.persistent_prefix for l in server.lanes)
+                      if args.shards > 1 else server.persistent_prefix)
+        if persistent:
+            line += (f"  prefix_hits={server.stats.prefix_hits}"
+                     f"  prefix_evictions={server.stats.prefix_evictions}")
+        if gen.sparse_attention:
+            line += f"  pages_reclaimed={server.stats.pages_reclaimed}"
+        if args.lazy_reserve:
+            line += (f"  pages_deferred={server.stats.pages_deferred}"
+                     f"  window_stalls={server.stats.window_stalls}")
+    if args.preemption:
+        line += (f"  preemptions={server.stats.preemptions}"
+                 f"  pages_spilled={server.stats.pages_spilled}"
+                 f"  resume_p50={server.stats.resume_p50:.3f}s")
+    if args.deadline_s is not None:
+        line += f"  deadline_rejects={server.stats.deadline_rejects}"
+    if server.stats.poisoned_requests:
+        line += f"  poisoned_requests={server.stats.poisoned_requests}"
+    if args.shards > 1:
+        line += f"  lanes_in_flight={server.stats.lanes_in_flight:.2f}"
     print(line)
-    if args.runtime == "stream" and args.shards > 1:
+    if args.shards > 1:
         # per-shard gauge breakdown: placement + residency + pool usage of
         # each shard-local ledger (the multi-host monitoring surface)
         for g in server.shard_gauges():

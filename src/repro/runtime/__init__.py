@@ -16,4 +16,3 @@ from repro.runtime.scheduler import (  # noqa: F401
     SchedulerStats,
     StreamScheduler,
 )
-from repro.runtime.server import BatchServer, ServerStats  # noqa: F401

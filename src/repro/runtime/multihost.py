@@ -37,8 +37,7 @@ Design contract (docs/ARCHITECTURE.md §6a):
   padded ``prompt_len + gen_length``.  The ``disagg`` policy dedicates
   ``refresh_shards`` lanes to long prompts (full ``prompt_len``) and
   gives the remaining decode lanes a short ``decode_prompt_len``, so a
-  long prefill can no longer inflate decode p95 —
-  ``benchmarks.costmodel.disagg_report`` gives the analytic bound.
+  long prefill can no longer inflate decode p95.
 
 * **Lanes step together.**  One ``step()`` is a round: every lane with
   work dispatches its engine step (``StreamScheduler._dispatch``), and

@@ -21,10 +21,7 @@ class Request:
     enc_embeds: Optional[np.ndarray] = None
     request_id: int = dataclasses.field(default_factory=lambda: next(_ids))
     stream_cb: Optional[StreamCallback] = None   # per-block streaming hook
-    max_new_tokens: Optional[int] = None   # cap (rounded up to whole blocks);
-                                           # honoured by StreamScheduler only —
-                                           # the lock-step server always runs
-                                           # the full gen_length
+    max_new_tokens: Optional[int] = None   # cap (rounded up to whole blocks)
     sample_seed: Optional[int] = None      # per-request sampling seed (fold_in
                                            # index); defaults to request_id —
                                            # replay offline via

@@ -1,9 +1,8 @@
 """Continuous-batching scheduler over the slot-based engine state.
 
-Unlike the lock-step ``BatchServer`` (all B requests enter and leave
-together), the scheduler drives ``DiffusionEngine.step`` — ONE compiled
-program advancing every resident slot by one denoising iteration — and does
-all control flow host-side:
+Requests enter and leave slots one at a time: the scheduler drives
+``DiffusionEngine.step`` — ONE compiled program advancing every resident
+slot by one denoising iteration — and does all control flow host-side:
 
 * **slot admission** from a FIFO queue.  The engine's cadence is per-row
   (``EngineState.phase [B]``, mixed-mode step), so with
@@ -67,9 +66,9 @@ all control flow host-side:
   watchdog: zero forward progress (or a blown step/wall budget) raises a
   typed ``DrainStalled`` naming the stuck slots instead of hanging CI.
 
-``drain()`` keeps the offline contract of ``BatchServer`` (submit everything,
-call drain, read ``Request.output``), so existing callers keep working.
-docs/ARCHITECTURE.md documents the full memory-manager contract.
+``drain()`` is the offline mode (submit everything, call drain, read
+``Request.output``).  docs/ARCHITECTURE.md documents the full
+memory-manager contract.
 """
 from __future__ import annotations
 
@@ -243,7 +242,8 @@ class SchedulerStats:
             "lanes_in_flight": self.lanes_in_flight,
         }
 
-    # BatchServer.stats compatibility
+    # aliases of goodput / completed / tokens_out under the names the
+    # serve.py summary line and offline callers read
     @property
     def tps(self) -> float:
         return self.goodput
@@ -650,8 +650,7 @@ class StreamScheduler:
         self._completed: list[Request] = []
         # modality contract: encoder-conditioned archs need enc_embeds on
         # every request, others on none — validated at submit() so a mixed
-        # batch can never reach the compute path (BatchServer bug carried
-        # over as an up-front check here).
+        # batch can never reach the compute path.
         self.expects_enc = bool(model.cfg.n_encoder_layers) or \
             model.cfg.family in ("audio", "vlm")
         self._enc_out = None
@@ -1745,8 +1744,8 @@ class StreamScheduler:
     def drain(self, *, max_steps: Optional[int] = None,
               max_wall_s: Optional[float] = None) -> list[Request]:
         """Offline mode: run until queue, spill list, and slots are empty
-        (BatchServer compatible — submit everything, drain, read
-        ``Request.output`` / ``Request.error``).
+        (submit everything, drain, read ``Request.output`` /
+        ``Request.error``).
 
         Watchdog (ARCHITECTURE §5c): liveness bugs fail typed instead of
         hanging.  Three tripwires raise ``DrainStalled`` naming the stuck

@@ -6,7 +6,8 @@ The failure-handling contract this file pins down:
     class spills the lowest-priority resident at its block boundary; the
     victim's resumed output is BIT-IDENTICAL to an uninterrupted offline
     run — greedy and sampled alike (the draw-key numbering survives the
-    round trip);
+    round trip) — and under mixed classes no interactive request finishes
+    later than it would without preemption;
   * **SLO-aware admission**: higher classes admit first; a request whose
     wait + estimated service exceeds its ``deadline_s`` is rejected with a
     typed ``DeadlineUnmeetable``, never silently queued;
@@ -159,6 +160,54 @@ def test_preemption_needs_priority_gap(small_model):
     # FIFO held: a finished before b was admitted
     assert a.finish_s <= b.admit_s
 
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.7])
+def test_preemption_finishes_interactive_no_later(small_model, temperature):
+    """Mixed classes at a pool of exactly two full extents: three
+    full-length class-0 jobs at step 0, then a one-block class-1 request
+    every 5 steps.  Without preemption the interactive class waits for a
+    batch extent to retire; with it a batch resident spills to host.  Each
+    interactive request finishes at an engine step no later than without
+    preemption, at least one spill happens, and every output is the same
+    under both."""
+    cfg, model, params = small_model
+    plen, gen_length = 24, 32           # t_total = 56 -> 7 vpages a slot
+    gen = _es_cfg(gen_length=gen_length, temperature=temperature,
+                  skip_stages=(SkipStage(1, 0.5), SkipStage(2, 0.5)))
+    served = {}
+    for preempt in (False, True):
+        rng = np.random.default_rng(21)
+        mk = lambda: rng.integers(3, cfg.vocab_size, plen).astype(np.int32)
+        batch = [Request(prompt=mk(), priority=0, sample_seed=i)
+                 for i in range(3)]
+        inter = [Request(prompt=mk(), priority=1, max_new_tokens=8,
+                         sample_seed=10 + i) for i in range(4)]
+        sched = StreamScheduler(model, params, gen, max_slots=4,
+                                prompt_len=plen, paged=True, page_size=PS,
+                                kv_pages=2 * (plen + gen_length) // PS + 1,
+                                preemption=preempt)
+        for r in batch:
+            sched.submit(r)
+        arrivals = {5 * (1 + i): r for i, r in enumerate(inter)}
+        steps, finished = 0, {}
+        while arrivals or sched.has_work():
+            if steps in arrivals:
+                sched.submit(arrivals.pop(steps))
+            assert sched.step(), "the batch jobs keep a step running"
+            steps += 1
+            for r in inter:
+                if r.output is not None:
+                    finished.setdefault(r.request_id, steps)
+        assert sched.stats.completed == len(batch) + len(inter)
+        assert all(r.error is None for r in batch + inter)
+        served[preempt] = ([finished[r.request_id] for r in inter],
+                           [r.output.tolist() for r in batch + inter],
+                           sched.stats.preemptions)
+    (off, off_out, _), (on, on_out, preemptions) = served[False], served[True]
+    assert preemptions >= 1
+    assert all(a <= b for a, b in zip(on, off)), (on, off)
+    assert on_out == off_out
 
 # ---------------------------------------------------------------------------
 # SLO admission: priority classes + typed deadline verdicts

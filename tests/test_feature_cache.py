@@ -19,7 +19,10 @@ contract"):
     interpret mode;
   * the cadence: the k-th scheduled refresh is FULL iff
     ``k % cache_prompt_interval == 0``, and a block's first iteration is
-    always FULL.
+    always FULL;
+  * quality: on long prompts refreshed every iteration, greedy serving
+    with partial refreshes agrees with the uncached engine on at least
+    ``AGREEMENT_FLOOR`` of the tokens.
 """
 import dataclasses
 
@@ -390,3 +393,70 @@ def test_adaptive_cache_gating(small_model):
     with pytest.raises(AssertionError):
         DiffusionEngine(model, _cfg(skip_stages=(),
                                     cache_prompt_interval=2))
+
+
+# ---------------------------------------------------------------------------
+# quality: partial refreshes against the uncached engine
+# ---------------------------------------------------------------------------
+
+LONG_PROMPT_LEN = 600               # the refresh-dominated regime the cache
+LONG_GEN = 16                       # targets: 2 blocks of 8 per request
+CACHE_PROMPT_INTERVAL = 8           # 1 FULL + 7 PARTIAL refreshes per block
+AGREEMENT_FLOOR = 0.80
+
+
+def _long_cfg(**kw):
+    # a refresh every iteration (the recompute-everything regime) on an
+    # 8-layer stack whose probe is its first layer
+    return GenerationConfig(mode="es", gen_length=LONG_GEN, block_length=8,
+                            skip_stages=(SkipStage(1, 0.5),
+                                         SkipStage(2, 0.5)),
+                            prompt_refresh_period=1, block_refresh_period=4,
+                            **kw)
+
+
+def _serve_long(model, params, gcfg):
+    """Eight greedy full-length long prompts on SLOTS paged slots."""
+    rng = np.random.default_rng(9)
+    reqs = [Request(prompt=rng.integers(3, model.cfg.vocab_size,
+                                        LONG_PROMPT_LEN).astype(np.int32),
+                    max_new_tokens=LONG_GEN, sample_seed=i)
+            for i in range(8)]
+    n_vp = (LONG_PROMPT_LEN + LONG_GEN) // PS
+    sched = StreamScheduler(model, params, gcfg, max_slots=SLOTS,
+                            prompt_len=LONG_PROMPT_LEN, paged=True,
+                            page_size=PS, kv_pages=SLOTS * n_vp + 1,
+                            early_advance=True)
+    for r in reqs:
+        sched.submit(r)
+    done = {r.request_id: r.output for r in sched.drain()}
+    return np.stack([done[r.request_id] for r in reqs]), sched
+
+
+@pytest.fixture(scope="module")
+def long_prompt_uncached():
+    cfg = dataclasses.replace(configs.reduced(configs.get_config("llada-8b")),
+                              n_layers=8)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    return model, params, _serve_long(model, params, _long_cfg())[0]
+
+
+@pytest.mark.parametrize("fraction", [0.03125, 0.125])
+def test_partial_refresh_greedy_agreement(long_prompt_uncached, fraction):
+    """With 7 of every 8 refreshes PARTIAL (recomputing only the top
+    ``fraction`` most-varied past tokens), greedy serving of long prompts
+    agrees with the uncached engine on at least AGREEMENT_FLOOR of the
+    tokens, and the partial refreshes did run and skip work."""
+    model, params, uncached = long_prompt_uncached
+    out, sched = _serve_long(model, params, _long_cfg(
+        cache_prompt_interval=CACHE_PROMPT_INTERVAL,
+        cache_refresh_fraction=fraction))
+    st = sched.stats
+    # one event per refreshing row and step; the FULL ones are refresh_rows
+    partial = len(st.refresh_event_tokens) - st.refresh_rows
+    assert st.refresh_rows > 0
+    assert partial == (CACHE_PROMPT_INTERVAL - 1) * st.refresh_rows
+    assert 0.0 < st.cache_hit_fraction < 1.0
+    agreement = float((out == uncached).mean())
+    assert agreement >= AGREEMENT_FLOOR, agreement

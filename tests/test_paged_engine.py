@@ -358,6 +358,42 @@ def test_early_advance_sampled_equals_offline_replay(small_model):
             err_msg=f"early-advance sampled replay diverged for request {i}")
 
 
+
+@pytest.mark.parametrize("temperature", [0.0, 0.7])
+def test_early_advance_serves_in_fewer_steps(small_model, temperature):
+    """One arrival schedule in engine steps (a request every 3 steps,
+    lengths of 1, 2 or 4 blocks), parallel decoding at threshold 0 so a
+    block unmasks in one iteration, 4 slots at equal pool pages: early
+    advance serves it in strictly fewer ``step()`` calls than the aligned
+    cadence, which idles every slot out to the block boundary, and every
+    request's output is the same under both."""
+    cfg, model, params = small_model
+    plen, gen_length = 24, 32           # t_total = 56 -> 7 vpages a slot
+    gen = _es_cfg(gen_length=gen_length, parallel_decoding=True,
+                  pd_threshold=0.0, temperature=temperature,
+                  skip_stages=(SkipStage(1, 0.5), SkipStage(2, 0.5)))
+    served = {}
+    for early in (False, True):
+        rng = np.random.default_rng(0)
+        reqs = [Request(prompt=rng.integers(
+                    3, cfg.vocab_size, int(rng.integers(8, plen + 1))
+                ).astype(np.int32), max_new_tokens=(1, 2, 4, 1, 2)[i % 5] * 8,
+                    sample_seed=i) for i in range(8)]
+        sched = StreamScheduler(model, params, gen, max_slots=4,
+                                prompt_len=plen, paged=True, page_size=PS,
+                                kv_pages=4 * (plen + gen_length) // PS + 1,
+                                early_advance=early)
+        pending, tick, steps = list(reqs), 0, 0
+        while pending or sched.has_work():
+            while pending and 3 * (len(reqs) - len(pending)) <= tick:
+                sched.submit(pending.pop(0))
+            steps += sched.step()
+            tick += 1
+        assert sched.stats.completed == len(reqs)
+        served[early] = steps, [r.output.tolist() for r in reqs]
+    assert served[True][0] < served[False][0]
+    assert served[True][1] == served[False][1]
+
 def test_mid_cycle_admission_bit_identity(small_model):
     """Any-iteration admission WITHOUT parallel decoding: full-length blocks
     mean admitted rows prefill (phase 0) while residents sit mid-block in

@@ -20,7 +20,7 @@ from repro import configs
 from repro.configs import GenerationConfig, SkipStage
 from repro.core import make_engine
 from repro.models import build_model
-from repro.runtime import BatchServer, Request, StreamScheduler
+from repro.runtime import Request, StreamScheduler
 from repro.runtime.request import pad_and_stack
 
 PROMPT_LEN = 16
@@ -194,24 +194,3 @@ def test_encoder_family_streams(served):
     assert sched.engine.step_trace_count == 1
     with pytest.raises(ValueError, match="modality"):
         sched.submit(Request(prompt=np.arange(3, 9, dtype=np.int32)))
-
-
-def test_batchserver_groups_mixed_modality(served):
-    """The lock-step server must never np.stack a mixed batch: grouping at
-    step() keeps batches modality-homogeneous (the old code crashed when a
-    no-enc head batched with enc requests, or silently dropped enc when the
-    head had none)."""
-    cfg, model, params, gen = served
-    server = BatchServer(model, params, gen, batch_size=4,
-                         prompt_len=PROMPT_LEN)
-    rng = np.random.default_rng(2)
-    mk = lambda: rng.integers(3, cfg.vocab_size, 8).astype(np.int32)
-    # interleave modalities; llada has no cross-attn so enc_embeds are inert,
-    # but the batching layer must still not crash on the mixed queue
-    for i in range(5):
-        enc = np.zeros((4, cfg.d_model), np.float32) if i % 2 else None
-        server.submit(Request(prompt=mk(), enc_embeds=enc))
-    done = server.drain()
-    assert len(done) == 5
-    for r in done:
-        assert r.output is not None and (r.output < cfg.vocab_size).all()

@@ -17,7 +17,11 @@ Contract under test (docs/ARCHITECTURE.md §1c, dynamic-window contract):
     ``bs`` advances, and returns everything at retirement (no leak);
   * under pool pressure a row whose growth is denied STALLS and resumes —
     it is never killed and still produces the exact offline tokens;
-  * ``Request.max_blocks`` hard-caps the generated extent in every mode.
+  * ``Request.max_blocks`` hard-caps the generated extent in every mode;
+  * at equal pool pages lazy windowed admission holds at least 1.5x the
+    residents of eager full reservation, and windowed greedy serving
+    agrees with the unwindowed engine on at least ``AGREEMENT_FLOOR`` of
+    the tokens.
 """
 import dataclasses
 
@@ -360,3 +364,77 @@ def test_lazy_reserve_with_prefix_sharing(small_model, temperature):
         np.testing.assert_array_equal(
             outs[i], ref[i, PROMPT_LEN:],
             err_msg=f"lazy+sharing replay diverged for request {i}")
+
+
+# ---------------------------------------------------------------------------
+# long generation at equal pool pages: quality and admitted concurrency
+# ---------------------------------------------------------------------------
+
+LONG_GEN = 64                       # 8 blocks; t_total = 80 -> 10 vpages
+POOL_PAGES = 29                     # one page short of three full extents
+AGREEMENT_FLOOR = 0.80
+
+
+def _long_cfg(**kw):
+    return GenerationConfig(mode="es", gen_length=LONG_GEN, block_length=8,
+                            skip_stages=(SkipStage(1, 0.5),
+                                         SkipStage(2, 0.5)),
+                            prompt_refresh_period=8, block_refresh_period=4,
+                            **kw)
+
+
+def _serve_long(model, params, gcfg, lazy):
+    """Eight greedy full-prompt, full-length requests on 8 paged slots
+    over a POOL_PAGES pool (+ the garbage page)."""
+    reqs = _requests(model.cfg, 8, seed=11)
+    sched = StreamScheduler(model, params, gcfg, max_slots=8,
+                            prompt_len=PROMPT_LEN, paged=True, page_size=PS,
+                            kv_pages=POOL_PAGES + 1, early_advance=True,
+                            lazy_reserve=lazy)
+    for r in reqs:
+        sched.submit(r)
+    done = {r.request_id: r.output for r in sched.drain()}
+    return np.stack([done[r.request_id] for r in reqs]), sched
+
+
+@pytest.fixture(scope="module")
+def long_served(small_model):
+    """The unwindowed eager run, and windowed lazy runs built on demand."""
+    cfg, model, params = small_model
+    runs = {0: _serve_long(model, params, _long_cfg(), lazy=False)}
+
+    def get(window_blocks):
+        if window_blocks not in runs:
+            runs[window_blocks] = _serve_long(
+                model, params, _long_cfg(window_blocks=window_blocks),
+                lazy=True)
+        return runs[window_blocks]
+    return get
+
+
+@pytest.mark.parametrize("window_blocks", [5, 4])
+def test_windowed_greedy_agreement(long_served, window_blocks):
+    """Windowed lazy serving of long generations agrees with the
+    unwindowed eager replay on at least AGREEMENT_FLOOR of the greedy
+    tokens."""
+    full, _ = long_served(0)
+    out, sched = long_served(window_blocks)
+    assert sched.stats.completed == len(out)
+    agreement = float((out == full).mean())
+    assert agreement >= AGREEMENT_FLOOR, agreement
+
+
+def test_lazy_admission_concurrency_at_equal_pool(long_served):
+    """At the same POOL_PAGES, eager full reservation is page-gated at 2
+    residents while lazy windowed admission (prompt + window mapped, far
+    suffix deferred) holds at least 1.5x as many, and every request
+    defers exactly its far-suffix pages."""
+    _, eager = long_served(0)
+    _, lazy = long_served(5)
+    pages_full = -(-(PROMPT_LEN + LONG_GEN) // PS)
+    pages_admit = -(-(PROMPT_LEN + min(1 + 5, LONG_GEN // 8) * 8) // PS)
+    assert lazy.stats.resident_peak >= 1.5 * eager.stats.resident_peak
+    assert lazy.stats.pages_deferred == \
+        lazy.stats.completed * (pages_full - pages_admit)
+    assert eager.stats.pages_deferred == 0
+    assert eager.stats.window_stalls == 0
